@@ -9,11 +9,13 @@ produced by launch/roofline.py from the dry-run sweep, not here.
 ``{name, us_per_call, derived, status}`` — one per CSV row, plus one
 ``status: "error"`` record (with the traceback) per bench group that
 crashed, so the CI regression gate (benchmarks/check_regression.py)
-can distinguish "slow" from "crashed".  In JSON mode the exit code is
-0 even when a bench group fails: the per-bench statuses are the
-contract and the gate enforces them; without --json a failure still
-exits 1 (and prints the legacy ``name,nan,ERROR`` row) for direct
-shell use.
+can distinguish "slow" from "crashed".  A failed bench group prints a
+``name,nan,ERROR`` row and makes the harness exit 1, with or without
+``--json`` (the JSON is still written first).  The JSON's ``meta``
+names the device the numbers came from.
+
+The persistent compilation cache is turned on before the first compile
+(``repro.compile_cache``).  Everything runs in this one process.
 """
 from __future__ import annotations
 
@@ -25,21 +27,19 @@ import traceback
 
 
 def _env_meta() -> dict:
-    """Environment stamp for emitted JSON: which jax/backend produced
+    """Environment stamp for emitted JSON: which jax and device produced
     the numbers (regression diffs across environments are expected, and
     the gate needs to see that in the artifact, not guess)."""
     import platform
 
-    meta = {"python": platform.python_version()}
-    try:
-        import jax
-        meta.update(jax_version=jax.__version__,
-                    backend=jax.default_backend(),
-                    device_count=jax.device_count(),
-                    x64=bool(jax.config.jax_enable_x64))
-    except Exception as e:          # stamp what we can, never crash
-        meta["jax_error"] = repr(e)[:200]
-    return meta
+    import jax
+
+    dev = jax.devices()[0]
+    return {"python": platform.python_version(),
+            "jax_version": jax.__version__, "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": jax.device_count(),
+            "x64": bool(jax.config.jax_enable_x64)}
 
 
 def _parse_row(line: str) -> dict:
@@ -64,6 +64,9 @@ def main() -> None:
                     help="write structured per-bench records to OUT")
     args = ap.parse_args()
     quick = not args.full
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import async_rounds, cohort_scale, fig2_convergence, \
         layer_budget, mc_replicates, overhead, phy_solvers, \
@@ -116,7 +119,6 @@ def main() -> None:
             json.dump({"benches": records,
                        "meta": {"quick": quick, "groups": selected,
                                 **_env_meta()}}, f, indent=2)
-        return   # statuses recorded; the gate owns pass/fail
     if failed:
         sys.exit(1)
 

@@ -23,6 +23,7 @@ from repro.checkpoint.io import save_checkpoint
 from repro.data import TokenBatcher, make_token_stream, prefetch
 from repro.dist import (CompressorConfig, TrainHParams, build_train_step,
                         microbatch, train_input_shardings)
+from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.models.config import InputShape, ModelConfig
 
@@ -49,7 +50,7 @@ def main():
                       **PRESETS[args.preset])
     nd = jax.device_count()
     dm = 1
-    mesh = jax.make_mesh((nd // dm, dm), ("data", "model"))
+    mesh = make_mesh((nd // dm, dm), ("data", "model"))
     shape = InputShape("train", seq_len=args.seq,
                        global_batch=args.batch, kind="train")
     hp = TrainHParams(L_local=1, alpha=5e-3,

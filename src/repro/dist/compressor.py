@@ -389,9 +389,9 @@ def aggregate_flat_manual(flat: jnp.ndarray, comp: CompressorConfig,
         return mixed_res_wire_reduce(g_wire, weights, comp.bits, d,
                                      path=wp)
     recon, dw_q = mixed_recon(flat, comp)
-    from repro.kernels.ops import _default_interpret, sign_pad_len
+    from repro.kernels.ops import _interpret, sign_pad_len
     from repro.kernels.quant_pack import sign_dequant_reduce, signpack
-    interp = _default_interpret()
+    interp = _interpret(None)
     d_pad = sign_pad_len(d)
     padded = jnp.pad(flat, (0, d_pad - d)) if d_pad != d else flat
     words = signpack(padded.reshape(-1, 128), interpret=interp)  # [W, 4]

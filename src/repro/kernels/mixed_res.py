@@ -21,9 +21,10 @@ reduction, so the dense reconstruction never exists anywhere:
   words, uint32 high-resolution mask words (both in the ``signpack``
   ``[W, 4]`` layout) and ``b``-bit magnitude codes packed
   ``32 // bw`` per word in the ``packing.pack_codes`` layout;
-* **decode** (:func:`mixed_res_dequant_reduce`) — unpacks all G users'
-  wire buffers tile-by-tile and reduces ``sum_g w_g * recon_g`` in one
-  kernel; the per-user dense planes live only as one VMEM tile each.
+* **decode** (:func:`mixed_res_dequant_reduce`) — unpacks the G users'
+  wire buffers tile-by-tile and folds ``sum_g w_g * recon_g`` in one
+  kernel, users on the inner grid axis; the per-user dense planes live
+  only as one VMEM tile each.
 
 Layout convention (same as ``quant_pack.py``): the flat f32 vector is
 viewed as ``[W, 128]`` rows; sign/hi planes pack to ``[W, 4]`` uint32;
@@ -32,6 +33,12 @@ code *storage* width — the smallest of {2, 4, 8, 16} that holds ``b``
 bits (the paper's b = 10 stores in 16; the *accounted* payload uses
 the true ``b``, see DESIGN.md section 9).  A leading user axis U rides
 the grid, never a vmap.
+
+The per-user header row travels as a ``[U, 1, 8]`` f32 block (a full
+trailing window, which Mosaic accepts for any U) and is read and
+written as a vector: lanes are picked with an iota mask, never by a
+scalar store into VMEM.  Bit packing goes through ``quant_pack``'s
+MXU-exact :func:`pack_lanes` / :func:`unpack_lanes`.
 
 TARGET is TPU; on CPU the kernels run under interpret=True (see
 ``ops.py``).  The jnp oracles live in ``ref.py``.
@@ -44,7 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .quant_pack import BLOCK_ROWS
+from .quant_pack import BLOCK_ROWS, pack_lanes, unpack_lanes
 
 # wire-header lane assignment ([U, 8] f32 scalar rows).  Lane H_CHK
 # carries the bitcast uint32 xor-fold checksum of the packed planes
@@ -81,13 +88,18 @@ def _valid_mask(i, bm: int, d_valid: int):
 
 
 # ------------------------------------------------------------ pass A
+def _lane(lane: int):
+    """[1, HEADER_LANES] bool mask selecting one header lane."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, HEADER_LANES), 1) == lane
+
+
 def _reduce_kernel(x_ref, out_ref, *, lam: float, bm: int, d_valid: int,
                    masked: bool):
     """Grid (U, 2, T).  Phase 0 accumulates ||x||_inf; phase 1 (which
     reads the phase-0 result from the revisited output row) accumulates
     the threshold-masked min ``dw_q`` and the high-res count ``dbar``.
-    out_ref: [1, 8] f32 per user — revisited across (phase, tile), so
-    it stays resident in VMEM for the whole per-user reduction."""
+    out_ref: [1, 1, 8] f32 per user — revisited across (phase, tile),
+    so it stays resident in VMEM for the whole per-user reduction."""
     ph = pl.program_id(1)
     i = pl.program_id(2)
     absx = jnp.abs(x_ref[0])
@@ -98,25 +110,28 @@ def _reduce_kernel(x_ref, out_ref, *, lam: float, bm: int, d_valid: int,
 
     @pl.when(ph == 0)
     def _():
-        out_ref[0, H_INF] = jnp.maximum(out_ref[0, H_INF], jnp.max(absx))
+        row = out_ref[0]
+        out_ref[0] = jnp.where(_lane(H_INF),
+                               jnp.maximum(row, jnp.max(absx)), row)
+
+    @pl.when((ph == 1) & (i == 0))
+    def _():
+        out_ref[0] = jnp.where(_lane(H_DWQ), jnp.inf, out_ref[0])
 
     @pl.when(ph == 1)
     def _():
-        @pl.when(i == 0)
-        def _():
-            out_ref[0, H_DWQ] = jnp.inf
-
-        inf = out_ref[0, H_INF]
+        row = out_ref[0]
+        inf = row[:, H_INF:H_INF + 1]
         safe_inf = jnp.where(inf > 0, inf, 1.0)
         # the same per-element division the jnp reference uses (NOT
         # absx >= lam * inf, which rounds differently)
         hi = (absx / safe_inf) >= lam
         if masked:
             hi = hi & _valid_mask(i, bm, d_valid)
-        out_ref[0, H_DWQ] = jnp.minimum(
-            out_ref[0, H_DWQ], jnp.min(jnp.where(hi, absx, jnp.inf)))
-        out_ref[0, H_DBAR] = out_ref[0, H_DBAR] + jnp.sum(
-            hi.astype(jnp.float32))
+        dwq = jnp.min(jnp.where(hi, absx, jnp.inf))
+        cnt = jnp.sum(hi.astype(jnp.float32))
+        row = jnp.where(_lane(H_DWQ), jnp.minimum(row, dwq), row)
+        out_ref[0] = jnp.where(_lane(H_DBAR), row + cnt, row)
 
 
 def mixed_res_reduce(x: jnp.ndarray, lam: float, d_valid: int, *,
@@ -139,15 +154,16 @@ def mixed_res_reduce(x: jnp.ndarray, lam: float, d_valid: int, *,
     kernel = functools.partial(
         _reduce_kernel, lam=float(lam), bm=bm, d_valid=int(d_valid),
         masked=d_valid != W * 128)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(U, 2, W // bm),
         in_specs=[pl.BlockSpec((1, bm, 128), lambda u, p, i: (u, i, 0))],
-        out_specs=pl.BlockSpec((1, HEADER_LANES),
-                               lambda u, p, i: (u, 0)),
-        out_shape=jax.ShapeDtypeStruct((U, HEADER_LANES), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, HEADER_LANES),
+                               lambda u, p, i: (u, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((U, 1, HEADER_LANES), jnp.float32),
         interpret=interpret,
     )(x)
+    return out.reshape(U, HEADER_LANES)
 
 
 # ------------------------------------------------------------ pass B
@@ -158,20 +174,21 @@ def _emit_kernel(x_ref, head_ref, signs_ref, hi_ref, codes_ref, *,
     i = pl.program_id(1)
     x = x_ref[0]
     absx = jnp.abs(x)
-    inf = head_ref[0, H_INF]
-    dw_q = head_ref[0, H_DWQ]
-    step = head_ref[0, H_STEP]
+    head = head_ref[0]                                  # [1, 8]
+    inf = head[:, H_INF:H_INF + 1]
+    dw_q = head[:, H_DWQ:H_DWQ + 1]
+    step = head[:, H_STEP:H_STEP + 1]
     safe_step = jnp.where(step > 0, step, 1.0)
     if anchored:
         hi = absx >= dw_q                       # static-budget rule
     else:
         safe_inf = jnp.where(inf > 0, inf, 1.0)
-        hi = (absx / safe_inf) >= head_ref[0, H_LAM]   # eq. (6)
+        hi = (absx / safe_inf) >= head[:, H_LAM:H_LAM + 1]   # eq. (6)
     if masked:
         hi = hi & _valid_mask(i, bm, d_valid)
 
     # b-bit magnitude code on the [dw_q, inf] grid; low-res elements
-    # would produce negative codes — masked to 0 before the uint cast.
+    # would produce negative codes — masked to 0 before packing.
     # The clamp to the grid top is a no-op when the header's inf is the
     # true max (codes never exceed `levels` then), but an anchored
     # header from an approximate top-k (jax.lax.approx_max_k) can
@@ -180,19 +197,11 @@ def _emit_kernel(x_ref, head_ref, signs_ref, hi_ref, codes_ref, *,
     # clamped, the overshoot stays element-local (mag caps at inf),
     # like the jnp reference's behaviour.
     code = jnp.round((absx - dw_q) / safe_step)
-    code = jnp.minimum(jnp.where(hi, code, 0.0),
-                       float(levels)).astype(jnp.uint32)
+    code = jnp.minimum(jnp.where(hi, code, 0.0), float(levels))
 
-    shifts32 = jnp.arange(32, dtype=jnp.uint32)[None, None, :]
-    sbits = (x > 0).astype(jnp.uint32).reshape(bm, 4, 32)
-    signs_ref[0] = jnp.sum(sbits << shifts32, axis=-1, dtype=jnp.uint32)
-    hbits = hi.astype(jnp.uint32).reshape(bm, 4, 32)
-    hi_ref[0] = jnp.sum(hbits << shifts32, axis=-1, dtype=jnp.uint32)
-
-    per = 32 // bw                              # codes per uint32 word
-    cshift = (jnp.arange(per, dtype=jnp.uint32) * bw)[None, None, :]
-    cw = code.reshape(bm, 128 * bw // 32, per)
-    codes_ref[0] = jnp.sum(cw << cshift, axis=-1, dtype=jnp.uint32)
+    signs_ref[0] = pack_lanes((x > 0).astype(jnp.float32), 1)
+    hi_ref[0] = pack_lanes(hi.astype(jnp.float32), 1)
+    codes_ref[0] = pack_lanes(code, bw)
 
 
 def mixed_res_emit(x: jnp.ndarray, head: jnp.ndarray, b: int,
@@ -217,7 +226,8 @@ def mixed_res_emit(x: jnp.ndarray, head: jnp.ndarray, b: int,
         kernel,
         grid=(U, W // bm),
         in_specs=[pl.BlockSpec((1, bm, 128), lambda u, i: (u, i, 0)),
-                  pl.BlockSpec((1, HEADER_LANES), lambda u, i: (u, 0))],
+                  pl.BlockSpec((1, 1, HEADER_LANES),
+                               lambda u, i: (u, 0, 0))],
         out_specs=[pl.BlockSpec((1, bm, 4), lambda u, i: (u, i, 0)),
                    pl.BlockSpec((1, bm, 4), lambda u, i: (u, i, 0)),
                    pl.BlockSpec((1, bm, cpr), lambda u, i: (u, i, 0))],
@@ -225,44 +235,40 @@ def mixed_res_emit(x: jnp.ndarray, head: jnp.ndarray, b: int,
                    jax.ShapeDtypeStruct((U, W, 4), jnp.uint32),
                    jax.ShapeDtypeStruct((U, W, cpr), jnp.uint32)],
         interpret=interpret,
-    )(x, head)
+    )(x, head.reshape(U, 1, HEADER_LANES))
 
 
 # ------------------------------------------------------------- decode
-def _dequant_reduce_kernel(signs_ref, hi_ref, codes_ref, head_ref,
-                           w_ref, *rest, bw: int, bm: int):
-    """All G users' wire tiles -> one weighted-reduced f32 tile.  The
-    per-user dense reconstruction exists only as this VMEM tile.  With
-    an ``acc`` operand (cohort chunking) the tile is added on top of
-    the carried accumulator tile instead of overwriting it."""
+def _dequant_reduce_kernel(signs_ref, hi_ref, codes_ref, ws_ref, *rest,
+                           bw: int):
+    """Grid (tile, user).  One user's wire tile -> its weighted
+    reconstruction, folded into the output tile, which stays resident
+    across the user axis.  The per-user dense reconstruction exists
+    only as this VMEM tile.  The fold is the jnp oracle's left fold:
+    ``((acc + u_0) + u_1) + ...`` with an ``acc`` operand (cohort
+    chunking), ``(u_0 + u_1) + ...`` without."""
     if len(rest) == 2:
         acc_ref, out_ref = rest
     else:
         acc_ref, (out_ref,) = None, rest
-    G = signs_ref.shape[0]
-    shifts32 = jnp.arange(32, dtype=jnp.uint32)[None, None, None, :]
-    one = jnp.uint32(1)
+    g = pl.program_id(1)
+    sign = unpack_lanes(signs_ref[0], 1) != 0
+    hi = unpack_lanes(hi_ref[0], 1) != 0
+    code = unpack_lanes(codes_ref[0], bw).astype(jnp.float32)
+    ws = ws_ref[0]                                      # [1, 2]
+    wdq, wst = ws[:, 0:1], ws[:, 1:2]
+    # eq. (7)/(8): b-bit grid magnitude on the hi support, dw_q/2 off
+    # it, with the weight folded into the grid scalars as in the oracle
+    mag = jnp.where(hi, wdq + code * wst, wdq * 0.5)
+    term = jnp.where(sign, mag, -mag)
 
-    sbits = (signs_ref[...][..., None] >> shifts32) & one   # [G,bm,4,32]
-    signs = sbits.astype(jnp.float32).reshape(G, bm, 128) * 2.0 - 1.0
-    hbits = (hi_ref[...][..., None] >> shifts32) & one
-    hi = hbits.reshape(G, bm, 128) > 0
+    @pl.when(g == 0)
+    def _():
+        out_ref[...] = term if acc_ref is None else acc_ref[...] + term
 
-    per = 32 // bw
-    cshift = (jnp.arange(per, dtype=jnp.uint32) * bw)[None, None, None, :]
-    cmask = jnp.uint32((1 << bw) - 1)
-    code = ((codes_ref[...][..., None] >> cshift) & cmask).astype(
-        jnp.float32).reshape(G, bm, 128)
-
-    dw_q = head_ref[:, H_DWQ].reshape(G, 1, 1)
-    step = head_ref[:, H_STEP].reshape(G, 1, 1)
-    # eq. (7)/(8): b-bit grid magnitude on the hi support, dw_q/2 off it
-    mag = jnp.where(hi, dw_q + code * step, dw_q * 0.5)
-    recon = signs * mag
-    red = jnp.einsum(
-        "g,gwl->wl", w_ref[...].reshape(G), recon,
-        preferred_element_type=jnp.float32)
-    out_ref[...] = red if acc_ref is None else acc_ref[...] + red
+    @pl.when(g > 0)
+    def _():
+        out_ref[...] = out_ref[...] + term
 
 
 def mixed_res_dequant_reduce(signs: jnp.ndarray, hi: jnp.ndarray,
@@ -275,35 +281,36 @@ def mixed_res_dequant_reduce(signs: jnp.ndarray, hi: jnp.ndarray,
     f32, weights: [G] f32 -> [W, 128] f32 = sum_g w_g * deq(wire_g).
 
     Fuses per-user wire decoding with the weighted multi-user reduce:
-    the G dense f32 reconstruction planes never hit HBM.  ``acc``
-    ([W, 128] f32, optional) adds the reduce on top of a carried
-    accumulator tile-by-tile, so cohort chunks of a large user axis
-    fold through one resident plane (DESIGN.md §12: the kernel's
-    chunked sum is ``acc + einsum(chunk)``, ulp-level order-sensitive
-    across chunkings — the jnp oracle's sequential fold is the
-    chunking-invariant reference)."""
+    the G dense f32 reconstruction planes never hit HBM.  Users ride
+    the inner grid axis, so VMEM holds one user's tile per plane
+    whatever G is.  ``acc`` ([W, 128] f32, optional) adds the reduce
+    on top of a carried accumulator tile-by-tile, so cohort chunks of a
+    large user axis fold through one resident plane (DESIGN.md §12: the
+    same left fold as the jnp oracle, so chunking does not change it)."""
     G, W, _ = signs.shape
     bm = min(block_rows, W)
     assert W % bm == 0, (W, bm)
     bw = code_width(b)
     cpr = code_words_per_row(b)
     assert codes.shape == (G, W, cpr), (codes.shape, cpr)
-    kernel = functools.partial(_dequant_reduce_kernel, bw=bw, bm=bm)
-    in_specs = [pl.BlockSpec((G, bm, 4), lambda i: (0, i, 0)),
-                pl.BlockSpec((G, bm, 4), lambda i: (0, i, 0)),
-                pl.BlockSpec((G, bm, cpr), lambda i: (0, i, 0)),
-                pl.BlockSpec((G, HEADER_LANES), lambda i: (0, 0)),
-                pl.BlockSpec((G, 1), lambda i: (0, 0))]
-    args = [signs, hi, codes, head, weights.reshape(G, 1)]
+    kernel = functools.partial(_dequant_reduce_kernel, bw=bw)
+    w = weights.astype(jnp.float32)
+    # the oracle's per-user grid scalars w*dw_q and w*step
+    ws = jnp.stack([w * head[:, H_DWQ], w * head[:, H_STEP]], axis=1)
+    in_specs = [pl.BlockSpec((1, bm, 4), lambda i, g: (g, i, 0)),
+                pl.BlockSpec((1, bm, 4), lambda i, g: (g, i, 0)),
+                pl.BlockSpec((1, bm, cpr), lambda i, g: (g, i, 0)),
+                pl.BlockSpec((1, 1, 2), lambda i, g: (g, 0, 0))]
+    args = [signs, hi, codes, ws.reshape(G, 1, 2)]
     if acc is not None:
         assert acc.shape == (W, 128), acc.shape
-        in_specs.append(pl.BlockSpec((bm, 128), lambda i: (i, 0)))
+        in_specs.append(pl.BlockSpec((bm, 128), lambda i, g: (i, 0)))
         args.append(acc.astype(jnp.float32))
     return pl.pallas_call(
         kernel,
-        grid=(W // bm,),
+        grid=(W // bm, G),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, 128), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, 128), lambda i, g: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((W, 128), jnp.float32),
         interpret=interpret,
     )(*args)

@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU backends (this container) and
-False on TPU, so the same call sites work in both environments.
+``interpret`` defaults to True on CPU backends and False on TPU, so the
+same call sites work in both environments.  Interpret mode is refused
+on a TPU: there the compiled kernels run, never the interpreter.
 """
 from __future__ import annotations
 
@@ -24,8 +25,17 @@ from .quant_pack import signpack as _signpack
 from .wire import WirePath, check_packed_dim
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret(interpret: bool | None) -> bool:
+    """Resolve a per-call ``interpret`` flag: None picks the backend's
+    default (interpret everywhere but a TPU); True is refused on a
+    TPU, where the compiled kernels always run."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode is the CPU correctness "
+                         "harness; on a TPU the compiled kernels run")
+    return interpret
 
 
 def _default_use_kernel(use_kernel: bool | None) -> bool:
@@ -47,10 +57,12 @@ def _resolve_lowering(path: WirePath | None, interpret: bool | None,
     honored when no spec is given (they remain the kernel test suite's
     harness knobs)."""
     if path is not None:
-        return (path.interpret() if interpret is None else interpret,
-                path.use_kernel() if use_kernel is None else use_kernel)
-    return (_default_interpret() if interpret is None else interpret,
-            _default_use_kernel(use_kernel))
+        kern = path.use_kernel() if use_kernel is None else use_kernel
+        if interpret is None:
+            interpret = path.interpret()
+    else:
+        kern = _default_use_kernel(use_kernel)
+    return kern and _interpret(interpret), kern
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -59,7 +71,7 @@ def signpack_op(x: jnp.ndarray, interpret: bool | None = None
     """Pack the sign plane of a flat f32 vector.
 
     x: [d] f32 with d % 128 == 0  ->  [d/32] uint32 (viewed flat)."""
-    interp = _default_interpret() if interpret is None else interpret
+    interp = _interpret(interpret)
     words = _signpack(x.reshape(-1, 128), interpret=interp)
     return words.reshape(-1)
 
@@ -68,7 +80,7 @@ def signpack_op(x: jnp.ndarray, interpret: bool | None = None
 def sign_dequant_reduce_op(words: jnp.ndarray, scales: jnp.ndarray,
                            interpret: bool | None = None) -> jnp.ndarray:
     """words: [G, d/32] u32, scales: [G] -> [d] f32 weighted sign sum."""
-    interp = _default_interpret() if interpret is None else interpret
+    interp = _interpret(interpret)
     G = words.shape[0]
     out = _sdr(words.reshape(G, -1, 4), scales, interpret=interp)
     return out.reshape(-1)
@@ -95,7 +107,7 @@ def packed_sign_weighted_sum(flat: jnp.ndarray, scales: jnp.ndarray,
     fuses per-peer unpacking with the scale-weighted reduction.  Not
     jitted here — call sites trace it into their own jitted steps.
     """
-    interp = _default_interpret() if interpret is None else interpret
+    interp = _interpret(interpret)
     G, d = flat.shape
     d_pad = sign_pad_len(d)
     if d_pad != d:
@@ -379,7 +391,7 @@ def flash_decode_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     q: [B, H, D]; k/v: [B, S, Hkv, D(v)]; length: scalar int32.
     Returns [B, H, Dv]."""
-    interp = _default_interpret() if interpret is None else interpret
+    interp = _interpret(interpret)
     B, H, D = q.shape
     Hkv = k.shape[2]
     G = H // Hkv

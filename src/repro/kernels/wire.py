@@ -158,9 +158,11 @@ class WirePath:
         return self.lowering == "kernel"
 
     def interpret(self) -> bool:
-        """Pallas interpret mode — the correctness harness everywhere
-        but real TPU hardware."""
-        return jax.default_backend() != "tpu"
+        """Pallas interpret mode: the CPU correctness harness for the
+        kernel lowering.  True only when the kernels run
+        (:meth:`use_kernel`) on a backend that is not a TPU; on a TPU
+        the compiled kernels always run."""
+        return self.use_kernel() and jax.default_backend() != "tpu"
 
     @property
     def streaming(self) -> bool:

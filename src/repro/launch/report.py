@@ -16,14 +16,18 @@ import glob
 import json
 import os
 
-from repro.launch.roofline import load_results, markdown_table, \
-    roofline_row
+from repro.launch.roofline import (DRYRUN_TARGET, device_peaks,
+                                   load_results, markdown_table,
+                                   roofline_row)
 
-HW_NOTE = ("Hardware basis: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, "
-           "50 GB/s/link ICI; 256 chips/pod (16x16), 512 for multi-pod "
-           "(2x16x16). All per-device quantities from post-SPMD HLO "
-           "with trip-count-aware loop accounting "
-           "(src/repro/launch/hlo_analysis.py).")
+_PEAKS = device_peaks(DRYRUN_TARGET)
+HW_NOTE = (f"Hardware basis: {DRYRUN_TARGET} — "
+           f"{_PEAKS['bf16_flops'] / 1e12:g} TFLOP/s bf16, "
+           f"{_PEAKS['hbm_bw'] / 1e9:g} GB/s HBM, "
+           f"{_PEAKS['ici_link_bw'] / 1e9:g} GB/s/link ICI; 256 chips/pod "
+           "(16x16), 512 for multi-pod (2x16x16). All per-device "
+           "quantities from post-SPMD HLO with trip-count-aware loop "
+           "accounting (src/repro/launch/hlo_analysis.py).")
 
 
 def dryrun_table(runs: str, mesh: str) -> str:

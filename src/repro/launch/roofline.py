@@ -1,9 +1,11 @@
 """Roofline report: three terms per (arch x shape x mesh) from the
 dry-run sweep JSONs.
 
-    compute    = per-device HLO FLOPs / 197 TFLOP/s  (bf16 peak)
-    memory     = per-device HBM bytes / 819 GB/s
-    collective = per-device collective bytes / 50 GB/s ICI link
+    compute    = per-device HLO FLOPs / peak bf16 FLOP/s
+    memory     = per-device HBM bytes / peak HBM bytes/s
+    collective = per-device collective bytes / one ICI link's bytes/s
+
+with the peaks of the target chip from :data:`DEVICE_PEAKS`.
 
 All inputs are already per-device (post-SPMD HLO shapes), so no /chips
 is applied — dividing the global quantities by chip count gives the
@@ -21,9 +23,26 @@ import json
 import os
 from typing import Dict, List
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Published per-chip peaks, keyed by jax's ``Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1 600 Gbit/s of inter-chip interconnect over
+# four links (50 GB/s each).  A kind missing here is an error, never a
+# default: a number divided by another chip's peak means nothing.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9},
+}
+# The dry-run sweep compiles on host devices and projects onto this chip.
+DRYRUN_TARGET = "TPU v5 lite"
+
+
+def device_peaks(kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``kind``; raises KeyError for a kind that
+    has no entry in :data:`DEVICE_PEAKS`."""
+    if kind not in DEVICE_PEAKS:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"have {sorted(DEVICE_PEAKS)}")
+    return DEVICE_PEAKS[kind]
 
 
 def load_results(runs_dir: str, mesh: str = "single") -> List[Dict]:
@@ -40,9 +59,10 @@ def roofline_row(r: Dict) -> Dict:
     if r["status"] != "ok":
         return {"arch": r["arch"], "shape": r["shape"],
                 "status": r["status"]}
-    t_comp = r["flops"] / PEAK_FLOPS
-    t_mem = r["hbm_bytes"] / HBM_BW
-    t_coll = r["collective_bytes"] / ICI_BW
+    peaks = device_peaks(DRYRUN_TARGET)
+    t_comp = r["flops"] / peaks["bf16_flops"]
+    t_mem = r["hbm_bytes"] / peaks["hbm_bw"]
+    t_coll = r["collective_bytes"] / peaks["ici_link_bw"]
     terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())

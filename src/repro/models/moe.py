@@ -37,12 +37,8 @@ def _inner_mesh(mesh):
     """Mesh argument for a shard_map that may be nested inside a
     partial-manual region: the context's AbstractMesh when one is
     active (required for nesting), else the concrete mesh."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            return None            # infer from context
-    except Exception:
-        pass
+    if jax.sharding.get_abstract_mesh().axis_names:
+        return None                # infer from context
     return mesh
 
 

@@ -49,51 +49,22 @@ def logical_to_spec(axes: Sequence[Optional[str]]) -> P:
 def _manual_axes() -> frozenset:
     """Mesh axes that are Manual in the current trace context (inside a
     shard_map region) — constraints must not mention them."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or not am.axis_names:
-            return frozenset()
-        return frozenset(
-            n for n, t in zip(am.axis_names, am.axis_types)
-            if t == jax.sharding.AxisType.Manual)
-    except Exception:
-        pass
-    # jax 0.4.x has no abstract-mesh query; axis names bound by an
-    # enclosing shard_map/pmap live in the trace axis env instead
-    # (vmap's spmd_axis_name deliberately does NOT appear — those
-    # constraints are extended by the vmap machinery itself).
-    try:
-        names = jax.core.unsafe_get_axis_names_DO_NOT_USE()
-        return frozenset(n for n in names if isinstance(n, str))
-    except Exception:
-        return frozenset()
+    am = jax.sharding.get_abstract_mesh()
+    return frozenset(n for n, t in zip(am.axis_names, am.axis_types)
+                     if t == jax.sharding.AxisType.Manual)
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """Version-portable ``shard_map`` front-end.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=, check_vma=)``
-    where ``axis_names`` lists the MANUAL mesh axes (the rest stay
-    GSPMD-auto).  jax 0.4.x instead has
-    ``jax.experimental.shard_map.shard_map(..., auto=, check_rep=)``
-    where ``auto`` lists the NON-manual axes.  Both the repro.dist
-    runtime and tests/dist_checks.py go through this wrapper so the
-    same source runs on either API.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset()
+    """``jax.shard_map`` with the repo's defaults: ``axis_names`` lists
+    the MANUAL mesh axes (the rest stay GSPMD-auto); None makes every
+    axis manual.  The repro.dist runtime and tests/dist_checks.py go
+    through this front-end."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=bool(check_vma),
-                      auto=auto)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
 def shard(x: jax.Array, *axes: Optional[str]) -> jax.Array:

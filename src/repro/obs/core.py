@@ -211,8 +211,12 @@ def session(jsonl: Optional[str] = None, memory: bool = True,
                       profile_dir=profile_dir,
                       retrace_storm=retrace_storm)
     _ACTIVE = sess
+    import jax  # local, as in jit_tap: importing obs touches no jax
+
+    dev = jax.devices()[0]
     sess.emit("session", "start", jit_stream=jit_stream,
-              jsonl=jsonl or "")
+              jsonl=jsonl or "", platform=dev.platform,
+              device_kind=dev.device_kind, device_count=jax.device_count())
     try:
         yield sess
     finally:

@@ -11,9 +11,9 @@ Sections (each present only when the trace carries its events):
   phy solve, accuracy and latency-budget burn-down;
 * phase-time breakdown — total seconds and share per phase name
   ("where did the round time go");
-* wire traffic — bytes moved by the fused encode/decode kernels and
-  the attained bandwidth over the train phase vs the roofline HBM
-  bound ("is the wire path memory-bound yet");
+* wire traffic — bytes moved by the fused encode/decode kernels, their
+  rate over the train phase and, on a TPU, that rate over the chip's
+  HBM peak ("is the wire path memory-bound yet");
 * async rounds — the event-clock telemetry from the async round
   engine (``engine.async`` events): arrivals and staleness per round,
   effective participation, straggler gap, buffer occupancy and
@@ -31,10 +31,7 @@ import csv
 import json
 from typing import Any, Dict, List, Optional
 
-try:                                    # repo-local roofline constants
-    from repro.launch.roofline import HBM_BW
-except Exception:                       # standalone use of the CLI
-    HBM_BW = 819e9
+from repro.launch.roofline import device_peaks
 
 
 def load_events(path: str) -> List[Dict[str, Any]]:
@@ -116,9 +113,22 @@ def per_round_table(events: List[Dict]) -> List[Dict[str, Any]]:
     return rows
 
 
+def trace_device(events: List[Dict]) -> Dict[str, Any]:
+    """The device the session ran on, from its start event
+    (``platform``, ``device_kind``, ``device_count``)."""
+    for e in events:
+        if e.get("kind") == "session" and e.get("name") == "start":
+            return {k: e.get(k) for k in ("platform", "device_kind",
+                                          "device_count")}
+    return {}
+
+
 def wire_summary(events: List[Dict]) -> Dict[str, float]:
-    """Aggregate fused encode/decode traffic and the attained train-
-    phase bandwidth vs the roofline HBM bound."""
+    """Aggregate fused encode/decode traffic, and the wire bytes per
+    second of train-phase wall clock.  Only a trace from a TPU gets a
+    ``roofline_fraction`` (that rate over the chip's HBM peak, from the
+    peaks table — an unknown TPU kind raises); on any other backend
+    the rate is a host-clock count, not a device metric."""
     enc_in = enc_out = dec_in = dec_out = 0.0
     calls = 0
     for e in events:
@@ -143,8 +153,12 @@ def wire_summary(events: List[Dict]) -> Dict[str, float]:
            "wire_calls": float(calls), "total_bytes": total,
            "compression_ratio": enc_in / enc_out if enc_out else 0.0}
     if train_s > 0:
-        out["attained_gbps"] = total / train_s / 1e9
-        out["roofline_fraction"] = (total / train_s) / HBM_BW
+        out["wire_gbps"] = total / train_s / 1e9
+        dev = trace_device(events)
+        if dev.get("platform") == "tpu":
+            out["roofline_fraction"] = ((total / train_s)
+                                        / device_peaks(
+                                            dev["device_kind"])["hbm_bw"])
     return out
 
 

@@ -113,6 +113,7 @@ from typing import List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.channel import (ChannelRealization, computation_latency,
                                 make_channel)
@@ -750,7 +751,7 @@ class VectorizedFLEngine:
             # cohort streaming scans the user axis on one device —
             # __init__ already warned if a mesh was also configured
             return None, None
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding
         if "data" not in getattr(mesh, "shape", {}):
             warnings.warn("engine mesh has no 'data' axis; user-axis "
                           "sharding disabled", stacklevel=2)
@@ -803,6 +804,29 @@ class VectorizedFLEngine:
         aux = {"s": s, "dbar": dbar.astype(jnp.int32), "r": inf - dw_q,
                "dw_q": dw_q, "inf": inf}
         return bits, aux
+
+    def _sharded_wire_aggregate(self, flat, weights):
+        """The packed-plane aggregation with the user axis sharded over
+        the engine mesh's "data" axis.  Mosaic kernels are not
+        partitioned automatically, so inside a shard_map each device
+        encodes its own users; the packed planes (never the dense
+        deltas) are all-gathered, and every device folds all K users in
+        user order.  That is the unsharded fold, so params and payload
+        bits equal the one-device run."""
+        q, d, wp = self.quantizer, self.d, self.wire_path_spec
+
+        def local(flat_l, w):
+            wire = mixed_res_encode(flat_l, q.lambda_, q.b, path=wp)
+            wire = jax.tree_util.tree_map(
+                lambda a: jax.lax.all_gather(a, "data", tiled=True), wire)
+            return mixed_res_wire_reduce(wire, w, q.b, d, path=wp), \
+                wire.head
+
+        agg, head = jax.shard_map(
+            local, mesh=self.engine_cfg.mesh, in_specs=(P("data"), P()),
+            out_specs=(P(), P()), check_vma=False)(flat, weights)
+        bits, aux = self._head_stats(head)
+        return agg, bits, aux
 
     def _cohort_accumulate(self, params, xs, ys, weights, faults=None):
         """Stream the stacked users through `lax.scan` in cohorts of
@@ -950,6 +974,9 @@ class VectorizedFLEngine:
                 if segments is not None:
                     agg, bits, aux = segmented_wire_aggregate(
                         flat, weights, segments, path=wp)
+                elif self._user_sharding is not None:
+                    agg, bits, aux = self._sharded_wire_aggregate(
+                        flat, weights)
                 else:
                     agg, bits, aux = _wire_aggregate(flat, weights,
                                                      q.lambda_, q.b,

@@ -25,12 +25,13 @@ from repro.dist import (CompressorConfig, TrainHParams,  # noqa: E402
                         decode_shardings, microbatch, param_shardings,
                         param_specs, shard_map, train_input_shardings)
 from repro.launch.inputs import input_specs  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import init_model  # noqa: E402
 from repro.models.config import InputShape  # noqa: E402
 
 
 def small_mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def check_aggregation_exact_mean():

@@ -14,10 +14,11 @@ from repro.core.quantize.static_budget import (static_budget_roundtrip,
                                                wire_bits)
 from repro.dist import (CompressorConfig, aggregate_delta, budget_k,
                         microbatch, mixed_recon, payload_bits, shard_map)
+from repro.launch.mesh import make_mesh
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _tree(rng, G=2):
@@ -192,7 +193,7 @@ def test_engine_mesh_without_data_axis_warns_and_disables():
     cnn = PaperCNNConfig(input_hw=8, channels=1, n_classes=2,
                          conv_filters=4, dense_units=8)
     fl = FLConfig(L=1, T=1, batch_size=8, seed=0)
-    mesh = jax.make_mesh((1, 1), ("pod", "model"))  # no "data" axis
+    mesh = make_mesh((1, 1), ("pod", "model"))  # no "data" axis
     with pytest.warns(UserWarning, match="no 'data' axis"):
         eng = VectorizedFLEngine(data, data, shards, cnn,
                                  MixedResolutionQuantizer(0.2, 8), None,

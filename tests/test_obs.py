@@ -303,7 +303,9 @@ def test_report_renders_wire_and_csv(tmp_path):
     wire = wire_summary(events)
     assert wire["encode_bytes_out"] == wire["decode_bytes_in"] > 0
     assert wire["compression_ratio"] > 1.0
-    assert 0 < wire["roofline_fraction"] < 1.0
+    # a CPU trace gets a host-clock rate, never a device roofline share
+    assert wire["wire_gbps"] > 0
+    assert "roofline_fraction" not in wire
 
     csv_out = str(tmp_path / "rounds.csv")
     text = render_report(events, csv_out=csv_out)
@@ -312,6 +314,38 @@ def test_report_renders_wire_and_csv(tmp_path):
         assert section in text
     header = open(csv_out).readline()
     assert header.startswith("round,")
+
+
+def _wire_trace(platform, kind):
+    return [{"kind": "session", "name": "start", "platform": platform,
+             "device_kind": kind, "device_count": 1},
+            {"kind": "jit", "name": "wire.encode", "bytes_in": 4e6,
+             "bytes_out": 1e6},
+            {"kind": "jit", "name": "wire.decode", "bytes_in": 1e6,
+             "bytes_out": 4e6},
+            {"kind": "phase", "name": "train_round", "dur_s": 0.5}]
+
+
+@pytest.mark.parametrize("platform,kind,share", [
+    ("tpu", "TPU v5 lite", 20e6 / 819e9),
+    ("cpu", "cpu", None),
+])
+def test_wire_roofline_share_only_from_a_known_tpu(platform, kind, share):
+    """The HBM roofline share comes from the peaks table of the traced
+    chip; a CPU trace reports the host-clock rate and no share."""
+    wire = wire_summary(_wire_trace(platform, kind))
+    assert wire["wire_gbps"] == pytest.approx(10e6 / 0.5 / 1e9)
+    if share is None:
+        assert "roofline_fraction" not in wire
+    else:
+        assert wire["roofline_fraction"] == pytest.approx(share)
+
+
+def test_unknown_device_kind_has_no_peaks():
+    from repro.launch.roofline import device_peaks
+    assert device_peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        wire_summary(_wire_trace("tpu", "TPU v99"))
 
 
 # ------------------------------------- engine verbose / log_every knob

@@ -68,19 +68,39 @@ def test_all_zero_sign_vector_decodes_minus_one():
         np.testing.assert_array_equal(out, -np.ones(d, np.float32))
 
 
+def expected_signs(x: np.ndarray) -> np.ndarray:
+    """The sign-plane contract: bit 1 (decoded +1) iff x > 0 after XLA's
+    flush of subnormals to zero, on the CPU as on the TPU.  A positive
+    subnormal therefore packs as "not positive" (-1)."""
+    normal_pos = x >= np.finfo(np.float32).tiny
+    return np.where(normal_pos, 1.0, -1.0)
+
+
+@pytest.mark.parametrize("x", [1.4e-45, -1.4e-45, 1e-39,
+                               float(np.finfo(np.float32).tiny)])
+def test_sign_of_subnormal_is_not_positive(x):
+    """Subnormals flush to zero, so their sign bit is 0; the smallest
+    normal float stays positive."""
+    xs = np.asarray([x, 1.0, -1.0], np.float32)
+    out = np.asarray(unpack_signs(pack_signs(jnp.asarray(xs)), 3))
+    np.testing.assert_array_equal(out, expected_signs(xs))
+    assert out[0] == (1.0 if x >= np.finfo(np.float32).tiny else -1.0)
+
+
 # -------------------------------------------------- hypothesis properties
 @settings(max_examples=50, deadline=None)
 @given(st.lists(st.floats(allow_nan=False, allow_infinity=False,
                           width=32),
                 min_size=0, max_size=200))
 def test_sign_roundtrip_property(xs):
-    """pack/unpack signs is a roundtrip of sign(x > 0) for ANY finite
-    float contents at ANY length (word-aligned or not)."""
+    """pack/unpack signs is a roundtrip of the sign-plane contract
+    (positive normal floats -> +1, everything else -> -1) for ANY
+    finite float contents at ANY length (word-aligned or not)."""
     x = np.asarray(xs, np.float32)
     words = pack_signs(jnp.asarray(x))
     assert words.shape == (-(-len(xs) // 32),)
     out = np.asarray(unpack_signs(words, len(xs)))
-    np.testing.assert_array_equal(out, np.where(x > 0, 1.0, -1.0))
+    np.testing.assert_array_equal(out, expected_signs(x))
 
 
 @settings(max_examples=50, deadline=None)

@@ -18,6 +18,8 @@ from __future__ import annotations
 import numpy as np
 from scipy.optimize import linprog
 
+from repro import obs
+
 from ..channel.cfmmimo import ChannelRealization
 from .base import PowerController, PowerSolution
 
@@ -66,7 +68,7 @@ class BisectionLPPowerControl(PowerController):
         B_tau = chan.cfg.pre_log
         lo, hi = 0.0, eta_upper_bound(chan, bits)
         eps = self.eps_rel * hi
-        best_p, best_eta, iters = np.ones(chan.cfg.K), 0.0, 0
+        best_p, best_eta, iters, lps = np.ones(chan.cfg.K), 0.0, 0, 0
         while hi - lo > eps and iters < self.max_iters:
             iters += 1
             mid = 0.5 * (lo + hi)
@@ -75,10 +77,12 @@ class BisectionLPPowerControl(PowerController):
                 hi = mid
                 continue
             theta = np.power(2.0, expo) - 1.0
+            lps += 1
             p = _feasible_powers(chan, theta)
             if p is not None:
                 lo, best_p, best_eta = mid, p, mid
             else:
                 hi = mid
+        obs.counter("phy.lp_solves", lps)
         return self._finish(chan, bits, best_p, eta=best_eta,
                             bisection_iters=iters)

@@ -8,6 +8,12 @@ One event = one flat JSON object.  Common envelope:
     name    str     dotted event name ("engine.round", "phy.solve", ...)
     ...             scalar payload fields + the active context tags
 
+A ``phase`` event (``trace.scope``, and the ``jax.compile`` span of each
+backend compile while a session is active) also carries ``dur_s``,
+``t0_ns`` / ``t1_ns`` on the wall clock (``time.time_ns()``, the clock a
+profiler trace records its start on) and ``parent``, the name of the
+enclosing open scope (None at the top).
+
 Sinks: an in-memory list (``ObsSession.events``, for tests and
 programmatic consumers) and a JSONL file (one event per line — what
 ``python -m repro.obs.report`` renders).
@@ -43,10 +49,12 @@ Public API / invariants:
 * Invariant 3 — the stream never holds a full tensor: array payloads
   are scalarized (0-d -> item, size <= 64 -> list, larger ->
   min/max/mean/size summary).
+* Spans and counters stay in memory (and in the JSONL file, when one
+  is given) until the session ends; nothing reads them back mid-run.
 * Consumers: ``python -m repro.obs.report`` renders a trace
   (per-round table, phase breakdown, wire traffic, async rounds,
-  retraces); sessions nest via the module-level active-session slot
-  under ``_LOCK``.
+  counter totals, retraces); sessions nest via the module-level
+  active-session slot under ``_LOCK``.
 """
 from __future__ import annotations
 
@@ -62,6 +70,9 @@ import numpy as np
 _LOCK = threading.Lock()
 _ACTIVE: Optional["ObsSession"] = None
 _MISSING = object()
+# jax.monitoring's time span around each backend compile (or load from
+# the persistent compilation cache), with the compiled function's name
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def active_session() -> Optional["ObsSession"]:
@@ -161,6 +172,24 @@ class ObsSession:
         # the global counts in repro.obs.trace survive across sessions)
         self.retraces: Dict[str, int] = {}
         self.profiling = False
+        # names of the open ``trace.scope``s, innermost last
+        self.stack: List[str] = []
+
+    def parent(self) -> Optional[str]:
+        """The innermost open scope's name, or None."""
+        return self.stack[-1] if self.stack else None
+
+    def on_time_span(self, event: str, start: float, end: float,
+                     **kwargs: Any) -> None:
+        """``jax.monitoring`` time-span listener: each backend compile
+        becomes a ``jax.compile`` phase span (``start``/``end`` are
+        ``time.time()`` seconds)."""
+        if event != COMPILE_EVENT:
+            return
+        self.emit("phase", "jax.compile", dur_s=end - start,
+                  t0_ns=int(start * 1e9), t1_ns=int(end * 1e9),
+                  parent=self.parent(),
+                  fun_name=str(kwargs.get("fun_name", "")))
 
     @property
     def events(self) -> List[Dict[str, Any]]:
@@ -213,13 +242,17 @@ def session(jsonl: Optional[str] = None, memory: bool = True,
     _ACTIVE = sess
     import jax  # local, as in jit_tap: importing obs touches no jax
 
-    dev = jax.devices()[0]
-    sess.emit("session", "start", jit_stream=jit_stream,
-              jsonl=jsonl or "", platform=dev.platform,
-              device_kind=dev.device_kind, device_count=jax.device_count())
+    jax.monitoring.register_event_time_span_listener(sess.on_time_span)
     try:
+        dev = jax.devices()[0]
+        sess.emit("session", "start", jit_stream=jit_stream,
+                  jsonl=jsonl or "", platform=dev.platform,
+                  device_kind=dev.device_kind,
+                  device_count=jax.device_count())
         yield sess
     finally:
+        jax.monitoring.unregister_event_time_span_listener(
+            sess.on_time_span)
         try:
             sess.close()
         finally:

@@ -9,15 +9,18 @@ Sections (each present only when the trace carries its events):
   percentiles streamed from inside the jitted round step, user-rate
   percentiles + straggler latency + solver iteration counts from the
   phy solve, accuracy and latency-budget burn-down;
-* phase-time breakdown — total seconds and share per phase name
-  ("where did the round time go");
-* wire traffic — bytes moved by the fused encode/decode kernels, their
-  rate over the train phase and, on a TPU, that rate over the chip's
-  HBM peak ("is the wire path memory-bound yet");
+* phase-time breakdown — per phase name, total seconds and self
+  seconds (the total less what nested phases cover), with the share of
+  self time ("where did the round time go");
+* wire traffic — bytes moved by the fused encode/decode kernels and
+  their rate over the train phase's host wall time (a host-clock
+  count: the kernels' device time is in a profiler trace);
 * async rounds — the event-clock telemetry from the async round
   engine (``engine.async`` events): arrivals and staleness per round,
   effective participation, straggler gap, buffer occupancy and
   dropped-upload totals;
+* counters — the total of each ``obs.counter`` over the session (as
+  ``phy.lp_solves``, the LPs the bisection power control solved);
 * recompilation summary — per-step trace counts from the retrace
   probes, flagging silent retrace storms;
 * profiler captures — directories of ``jax.profiler`` traces armed via
@@ -29,9 +32,8 @@ import argparse
 import collections
 import csv
 import json
+from bisect import bisect_right
 from typing import Any, Dict, List, Optional
-
-from repro.launch.roofline import device_peaks
 
 
 def load_events(path: str) -> List[Dict[str, Any]]:
@@ -50,14 +52,46 @@ def _num(v: Any) -> Optional[float]:
 
 
 # ------------------------------------------------------------- sections
+def _child_time_s(phases: List[Dict]) -> List[float]:
+    """Per phase event, the seconds its direct children cover: each
+    event naming a ``parent`` is charged to the latest-starting span of
+    that name, if that span holds it on the wall clock (``t0_ns`` /
+    ``t1_ns``).  Children of one span run one after another, so their
+    lengths add."""
+    spans: Dict[str, List[tuple]] = collections.defaultdict(list)
+    for i, e in enumerate(phases):
+        if "t0_ns" in e:
+            spans[e["name"]].append((e["t0_ns"], e["t1_ns"], i))
+    for v in spans.values():
+        v.sort()
+    covered = [0.0] * len(phases)
+    for e in phases:
+        cands = spans.get(e.get("parent"))
+        if not cands or "t0_ns" not in e:
+            continue
+        j = bisect_right(cands, e["t0_ns"], key=lambda c: c[0]) - 1
+        if j >= 0 and cands[j][1] >= e["t1_ns"]:
+            covered[cands[j][2]] += (e["t1_ns"] - e["t0_ns"]) * 1e-9
+    return covered
+
+
 def phase_breakdown(events: List[Dict]) -> List[Dict[str, Any]]:
-    """Total / count / mean duration per phase name, largest first."""
+    """Total / self / count / mean duration per phase name, largest
+    total first.  Self time is a phase's duration less what the phases
+    nested in it cover (found through each event's ``parent``), so a
+    nested span is not counted twice; a trace without wall-clock times
+    gives self time equal to total."""
+    phases = [e for e in events if e.get("kind") == "phase"]
+    covered = _child_time_s(phases)
     acc: Dict[str, List[float]] = collections.defaultdict(list)
-    for e in events:
-        if e.get("kind") == "phase":
-            acc[e["name"]].append(float(e.get("dur_s", 0.0)))
-    rows = [{"phase": name, "total_s": sum(d), "calls": len(d),
-             "mean_s": _mean(d)} for name, d in acc.items()]
+    own: Dict[str, float] = collections.defaultdict(float)
+    for e, c in zip(phases, covered):
+        d = float(e.get("dur_s", 0.0))
+        acc[e["name"]].append(d)
+        own[e["name"]] += max(d - c, 0.0)
+    rows = [{"phase": name, "total_s": sum(d), "self_s": own[name],
+             "calls": len(d), "mean_s": _mean(d)}
+            for name, d in acc.items()]
     rows.sort(key=lambda r: -r["total_s"])
     return rows
 
@@ -113,22 +147,12 @@ def per_round_table(events: List[Dict]) -> List[Dict[str, Any]]:
     return rows
 
 
-def trace_device(events: List[Dict]) -> Dict[str, Any]:
-    """The device the session ran on, from its start event
-    (``platform``, ``device_kind``, ``device_count``)."""
-    for e in events:
-        if e.get("kind") == "session" and e.get("name") == "start":
-            return {k: e.get(k) for k in ("platform", "device_kind",
-                                          "device_count")}
-    return {}
-
-
 def wire_summary(events: List[Dict]) -> Dict[str, float]:
     """Aggregate fused encode/decode traffic, and the wire bytes per
-    second of train-phase wall clock.  Only a trace from a TPU gets a
-    ``roofline_fraction`` (that rate over the chip's HBM peak, from the
-    peaks table — an unknown TPU kind raises); on any other backend
-    the rate is a host-clock count, not a device metric."""
+    second of train-phase wall clock (``wire_gbps``): a host-clock
+    count on any backend, never a share of a device peak, since the
+    train phase holds far more than the kernels (their device time,
+    and so their roofline share, comes from a profiler trace)."""
     enc_in = enc_out = dec_in = dec_out = 0.0
     calls = 0
     for e in events:
@@ -154,11 +178,6 @@ def wire_summary(events: List[Dict]) -> Dict[str, float]:
            "compression_ratio": enc_in / enc_out if enc_out else 0.0}
     if train_s > 0:
         out["wire_gbps"] = total / train_s / 1e9
-        dev = trace_device(events)
-        if dev.get("platform") == "tpu":
-            out["roofline_fraction"] = ((total / train_s)
-                                        / device_peaks(
-                                            dev["device_kind"])["hbm_bw"])
     return out
 
 
@@ -224,6 +243,16 @@ def resilience_summary(events: List[Dict]) -> Dict[str, float]:
     return out
 
 
+def counter_totals(events: List[Dict]) -> Dict[str, float]:
+    """Each counter's total, as flushed once per name when its session
+    closed; the totals of several sessions in one trace add up."""
+    out: Dict[str, float] = collections.defaultdict(float)
+    for e in events:
+        if e.get("kind") == "counter":
+            out[e["name"]] += float(e.get("total", 0.0))
+    return dict(sorted(out.items()))
+
+
 def retrace_summary(events: List[Dict]) -> List[Dict[str, Any]]:
     final: Dict[str, Dict[str, Any]] = {}
     for e in events:
@@ -278,11 +307,12 @@ def render_report(events: List[Dict],
                 w.writerows(rounds)
     phases = phase_breakdown(events)
     if phases:
-        total = sum(r["total_s"] for r in phases) or 1.0
+        total = sum(r["self_s"] for r in phases) or 1.0
         for r in phases:
-            r["share"] = f"{100.0 * r['total_s'] / total:.1f}%"
+            r["share"] = f"{100.0 * r['self_s'] / total:.1f}%"
         parts.append("== phase time ==\n" + _table(
-            phases, ["phase", "total_s", "calls", "mean_s", "share"]))
+            phases, ["phase", "total_s", "self_s", "calls", "mean_s",
+                     "share"]))
     wire = wire_summary(events)
     if wire:
         lines = [f"  {k}: {_fmt(v)}" for k, v in wire.items()]
@@ -295,6 +325,10 @@ def render_report(events: List[Dict],
     if resil:
         lines = [f"  {k}: {_fmt(v)}" for k, v in resil.items()]
         parts.append("== resilience ==\n" + "\n".join(lines))
+    counters = counter_totals(events)
+    if counters:
+        lines = [f"  {k}: {_fmt(v)}" for k, v in counters.items()]
+        parts.append("== counters ==\n" + "\n".join(lines))
     retraces = retrace_summary(events)
     if retraces:
         lines = [f"  {r['name']}: {r['count']} trace(s)"

@@ -1,9 +1,10 @@
 """Phase-level wall-clock tracing + jit recompilation detector.
 
-``scope("train_round")`` times a phase of the round lifecycle with an
-explicit ``jax.block_until_ready`` boundary (register the phase's
-device outputs with ``sc.block(...)``) so the recorded duration is real
-compute, not dispatch time.  ``round_scope(t)`` tags everything inside
+``scope("train_round")`` times a phase of the round lifecycle, placed
+on the wall clock and under its enclosing scope, with an optional
+``jax.block_until_ready`` boundary (register the phase's device outputs
+with ``sc.block(...)``) so the recorded duration is real compute, not
+dispatch time.  ``round_scope(t)`` tags everything inside
 with the round number and arms the optional ``jax.profiler`` capture
 when ``t == session.profile_round``.
 
@@ -46,7 +47,11 @@ class _Scope:
 
 @contextlib.contextmanager
 def scope(name: str, **tags):
-    """Time a phase; emits one ``kind: phase`` event with ``dur_s``.
+    """Time a phase; emits one ``kind: phase`` event with ``dur_s``
+    (``perf_counter``), its start and end ``t0_ns`` / ``t1_ns`` on the
+    wall clock (``time.time_ns()``) and ``parent``, the enclosing open
+    scope's name.  Blocks on the device only for values registered
+    with ``sc.block(...)``.
 
     Without an active session: zero work — yields an inert handle and
     never touches the clock or the device.
@@ -56,6 +61,9 @@ def scope(name: str, **tags):
     if sess is None:
         yield sc
         return
+    parent = sess.parent()
+    sess.stack.append(name)
+    t0_ns = time.time_ns()
     t0 = time.perf_counter()
     try:
         yield sc
@@ -63,7 +71,11 @@ def scope(name: str, **tags):
         if sc._block:
             import jax
             jax.block_until_ready(sc._block)
-        sess.emit("phase", name, dur_s=time.perf_counter() - t0, **tags)
+        dur = time.perf_counter() - t0
+        t1_ns = time.time_ns()
+        sess.stack.pop()
+        sess.emit("phase", name, dur_s=dur, t0_ns=t0_ns, t1_ns=t1_ns,
+                  parent=parent, **tags)
 
 
 @contextlib.contextmanager

@@ -1513,19 +1513,20 @@ class VectorizedFLEngine:
                 and (t - 1) % ecfg.redraw_channel_every == 0):
             state.chan = make_channel(state.chan.cfg,
                                       seed=ecfg.channel_seed + t)
-        # same nested draw order as the sequential loop
-        sel = np.stack([
-            np.stack([state.rng.choice(shard, self.take, replace=False)
-                      for _ in range(fl.L)])
-            for shard in self.shards])               # [K, L, b]
-        active = self._draw_active(state.part_rng)
+        with _obs.scope("engine.draw"):
+            # same nested draw order as the sequential loop
+            sel = np.stack([
+                np.stack([state.rng.choice(shard, self.take,
+                                           replace=False)
+                          for _ in range(fl.L)])
+                for shard in self.shards])           # [K, L, b]
+            active = self._draw_active(state.part_rng)
         if self._clusters > 1 and not ecfg.async_active:
             # two-level hierarchy: only one cluster's minibatches are
             # transferred (and resident) at a time
             return self._clustered_round(state, t, sel, active)
-        xs = jnp.asarray(self.dataset.x[sel])
-        ys = jnp.asarray(self.dataset.y[sel])
         faults = self._draw_faults(t)
+        extra = () if faults is None else (faults,)
         if ecfg.async_active:
             # async: busy users (mid-upload) keep transmitting their
             # old payload — only participating, non-busy users start a
@@ -1533,11 +1534,13 @@ class VectorizedFLEngine:
             # complete_round_async, once arrivals are known
             clock = state.async_clock
             fresh = active * (~clock.in_flight[0]).astype(np.float64)
+            xs, ys, fresh_dev = self._minibatches(sel, fresh)
             train_step, _ = self._async_steps(None)
-            clock.payload, state.qstate, bits, aux = train_step(
-                state.params, state.qstate, xs, ys,
-                jnp.asarray(fresh, jnp.float32),
-                *(() if faults is None else (faults,)))
+            with _obs.scope("engine.dispatch"):
+                clock.payload, state.qstate, bits, aux = train_step(
+                    state.params, state.qstate, xs, ys, fresh_dev,
+                    *extra)
+            bits_np, s_np = self._fetch(bits, aux)
             clock.uploads_started += int(fresh.sum())
             quarantined = 0
             if faults is not None:
@@ -1547,9 +1550,7 @@ class VectorizedFLEngine:
                 ok_np = np.asarray(aux["payload_ok"], bool)
                 quarantined = int(np.sum(fresh.astype(bool) & ~ok_np))
                 fresh = fresh * ok_np
-            bits_np = np.asarray(bits, np.float64) * fresh
-            s_np = np.asarray(aux["s"], np.float64) if "s" in aux \
-                else np.ones(self.K)
+            bits_np = bits_np * fresh
             fb = fresh.astype(bool)
             mean_s = float(np.mean(s_np[fb])) if fb.any() else 0.0
             return RoundWork(t=t, bits_np=bits_np, active=fresh,
@@ -1557,22 +1558,46 @@ class VectorizedFLEngine:
                              quarantined=quarantined)
         weights = self._round_weights(active)
         if not ecfg.effective_fused:
-            state.params, state.qstate, bits, aux = self._dense_round(
-                state.params, state.qstate, xs, ys, weights, active)
+            xs, ys = self._minibatches(sel)
+            with _obs.scope("engine.dispatch"):
+                state.params, state.qstate, bits, aux = \
+                    self._dense_round(state.params, state.qstate, xs, ys,
+                                      weights, active)
         else:
-            state.params, state.qstate, bits, aux = self._fused_step(
-                state.params, state.qstate, xs, ys,
-                jnp.asarray(weights, jnp.float32),
-                jnp.asarray(active, jnp.float32),
-                *(() if faults is None else (faults,)))
+            xs, ys, w_dev, a_dev = self._minibatches(sel, weights, active)
+            with _obs.scope("engine.dispatch"):
+                state.params, state.qstate, bits, aux = self._fused_step(
+                    state.params, state.qstate, xs, ys, w_dev, a_dev,
+                    *extra)
+        bits_np, s_np = self._fetch(bits, aux)
         quarantined = int(aux["quarantined"]) if faults is not None \
             else 0
-        bits_np = np.asarray(bits, np.float64) * active
-        s_np = np.asarray(aux["s"], np.float64) if "s" in aux \
-            else np.ones(self.K)
+        bits_np = bits_np * active
         mean_s = float(np.mean(s_np[active.astype(bool)]))
         return RoundWork(t=t, bits_np=bits_np, active=active,
                          mean_s=mean_s, quarantined=quarantined)
+
+    def _minibatches(self, sel: np.ndarray, *vectors: np.ndarray):
+        """The minibatches that ``sel`` indexes, gathered on the host
+        (``engine.gather``) and moved to the device with the round's
+        per-user ``vectors`` as float32 (``engine.h2d``).  On a TPU
+        ``jnp.asarray`` returns before the transfer ends, so the span
+        covers its start; the step waits for the rest, and the host
+        with it in ``engine.fetch``."""
+        with _obs.scope("engine.gather"):
+            x, y = self.dataset.x[sel], self.dataset.y[sel]
+        with _obs.scope("engine.h2d"):
+            return (jnp.asarray(x), jnp.asarray(y),
+                    *(jnp.asarray(v, jnp.float32) for v in vectors))
+
+    def _fetch(self, bits, aux) -> Tuple[np.ndarray, np.ndarray]:
+        """The round's payload bits and slot shares on the host
+        (``engine.fetch``), which waits for the step that makes them."""
+        with _obs.scope("engine.fetch"):
+            bits_np = np.asarray(bits, np.float64)
+            s_np = np.asarray(aux["s"], np.float64) if "s" in aux \
+                else np.ones(np.shape(bits_np))
+        return bits_np, s_np
 
     def _clustered_round(self, state: RunState, t: int, sel: np.ndarray,
                          active: np.ndarray) -> RoundWork:
@@ -1591,20 +1616,22 @@ class VectorizedFLEngine:
         groups = np.array_split(np.arange(self.K), self._clusters)
         total, heads = None, []
         for g in groups:
-            xs = jnp.asarray(self.dataset.x[sel[g]])
-            ys = jnp.asarray(self.dataset.y[sel[g]])
-            part, head = self._cluster_step(
-                state.params, xs, ys,
-                jnp.asarray(weights[g], jnp.float32))
-            total = part if total is None \
-                else self._combine_partials(total, part)
+            xs, ys, w_dev = self._minibatches(sel[g], weights[g])
+            with _obs.scope("engine.dispatch"):
+                part, head = self._cluster_step(state.params, xs, ys,
+                                                w_dev)
+                total = part if total is None \
+                    else self._combine_partials(total, part)
             heads.append(head)
-        state.params = self._apply_update(state.params, total)
-        # bits from the SAME jitted _head_stats graph the flat cohort
-        # step runs — payload accounting is bitwise cluster-invariant
-        bits, aux = self._head_stats_jit(jnp.concatenate(heads, axis=0))
-        bits_np = np.asarray(bits, np.float64) * active
-        s = np.asarray(aux["s"], np.float64)
+        with _obs.scope("engine.dispatch"):
+            state.params = self._apply_update(state.params, total)
+            # bits from the SAME jitted _head_stats graph the flat
+            # cohort step runs — payload accounting is bitwise
+            # cluster-invariant
+            bits, aux = self._head_stats_jit(
+                jnp.concatenate(heads, axis=0))
+        bits_np, s = self._fetch(bits, aux)
+        bits_np = bits_np * active
         mean_s = float(np.mean(s[active.astype(bool)]))
         return RoundWork(t=t, bits_np=bits_np, active=active,
                          mean_s=mean_s)
@@ -1675,8 +1702,7 @@ class VectorizedFLEngine:
                           for _ in range(fl.L)])
                 for shard in self.shards])
             for rng in state.rngs])                  # [R, K, L, b]
-        xs = jnp.asarray(self.dataset.x[sel])
-        ys = jnp.asarray(self.dataset.y[sel])
+        xs, ys = self._minibatches(sel)
         active = np.stack([self._draw_active(prng)
                            for prng in state.part_rngs])      # [R, K]
         faults = self._draw_faults(t, R)
@@ -1773,9 +1799,10 @@ class VectorizedFLEngine:
         accuracy = self.model_spec.accuracy
         accs = np.full(state.R, np.nan)
         rs = range(state.R) if alive is None else np.flatnonzero(alive)
-        for r in rs:
-            accs[r] = accuracy(self.replicate_params(state, int(r)),
-                               state.test_x, state.test_y)
+        with _obs.scope("eval"):
+            for r in rs:
+                accs[r] = accuracy(self.replicate_params(state, int(r)),
+                                   state.test_x, state.test_y)
         return accs
 
     def solve_uplink_host(self, chan: Optional[ChannelRealization],
@@ -1796,17 +1823,18 @@ class VectorizedFLEngine:
             # async corner: every participating user is mid-upload, so
             # nobody transmits fresh payload this round
             return UplinkSolution(0.0, per_user)
-        if len(act_idx) == self.K:
-            sol = self.power.solve(chan, np.maximum(bits_np, 1.0))
-            per_user = np.asarray(sol.latencies, np.float64)
-        else:
-            # churn: only active users transmit — solve the
-            # power-control problem on the sub-channel so
-            # absent users neither get power nor interfere
-            sol = self.power.solve(
-                _subchannel(chan, act_idx),
-                np.maximum(bits_np[act_idx], 1.0))
-            per_user[act_idx] = np.asarray(sol.latencies, np.float64)
+        with _obs.scope("engine.solve"):
+            if len(act_idx) == self.K:
+                sol = self.power.solve(chan, np.maximum(bits_np, 1.0))
+                per_user = np.asarray(sol.latencies, np.float64)
+            else:
+                # churn: only active users transmit — solve the
+                # power-control problem on the sub-channel so
+                # absent users neither get power nor interfere
+                sol = self.power.solve(
+                    _subchannel(chan, act_idx),
+                    np.maximum(bits_np[act_idx], 1.0))
+                per_user[act_idx] = np.asarray(sol.latencies, np.float64)
         return UplinkSolution(sol.straggler_latency, per_user)
 
     def solve_uplink_host_detailed(
@@ -1922,8 +1950,9 @@ class VectorizedFLEngine:
         state.cum_latency += uplink + self.comp_lat
         acc = None
         if self.eval_due(t):
-            acc = self.model_spec.accuracy(state.params, state.test_x,
-                                           state.test_y)
+            with _obs.scope("eval"):
+                acc = self.model_spec.accuracy(state.params, state.test_x,
+                                               state.test_y)
         quarantined = int(getattr(work, "quarantined", 0) or 0)
         state.logs.append(RoundLog(t, work.bits_np, uplink,
                                    self.comp_lat, state.cum_latency,

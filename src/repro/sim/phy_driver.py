@@ -414,14 +414,13 @@ def _run_scenario_lockstep_replicated(scn: Scenario,
             # only for replicates some cell still accounts (a replicate
             # dead in EVERY cell of the track is never logged again)
             track_acc: Dict[int, Optional[np.ndarray]] = {}
-            with _obs.scope("eval"):
-                for tr in live_tracks:
-                    track_acc[id(tr)] = (
-                        tr.engine.eval_accuracy_replicated(
-                            tr.state,
-                            alive=np.logical_or.reduce(
-                                [c.alive for c in tr.cells]))
-                        if tr.engine.eval_due(t) else None)
+            for tr in live_tracks:
+                track_acc[id(tr)] = (
+                    tr.engine.eval_accuracy_replicated(
+                        tr.state,
+                        alive=np.logical_or.reduce(
+                            [c.alive for c in tr.cells]))
+                    if tr.engine.eval_due(t) else None)
             with _obs.scope("finish_round"):
                 for cell, work, uplink, pu, fb, info in zip(
                         live, works, uplinks, per_user, fallbacks,

@@ -18,6 +18,7 @@ The contract the ISSUE pins:
 """
 import dataclasses
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -25,9 +26,10 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.obs.report import (load_events, per_round_table,
-                              phase_breakdown, render_report,
-                              retrace_summary, wire_summary)
+from repro.obs.report import (counter_totals, load_events,
+                              per_round_table, phase_breakdown,
+                              render_report, retrace_summary,
+                              wire_summary)
 from repro.sim import get_scenario, run_grid_batched
 
 pytestmark = pytest.mark.skipif(
@@ -86,6 +88,62 @@ def test_jsonl_event_schema_golden(tmp_path):
     assert load_events(path) == lines      # report loader round-trips
 
 
+def test_phase_spans_on_the_wall_clock_with_parents():
+    before = time.time_ns()
+    with obs.session() as sess:
+        with obs.scope("outer"):
+            with obs.scope("inner"):
+                with obs.scope("innermost"):
+                    pass
+            with obs.scope("sibling"):
+                pass
+    after = time.time_ns()
+    ph = {e["name"]: e for e in sess.events if e["kind"] == "phase"}
+    assert [e["name"] for e in sess.events if e["kind"] == "phase"] \
+        == ["innermost", "inner", "sibling", "outer"]    # at exit
+    assert ph["outer"]["parent"] is None
+    assert ph["inner"]["parent"] == ph["sibling"]["parent"] == "outer"
+    assert ph["innermost"]["parent"] == "inner"
+    for e in ph.values():
+        assert before <= e["t0_ns"] <= e["t1_ns"] <= after
+        assert e["dur_s"] <= (e["t1_ns"] - e["t0_ns"]) * 1e-9 + 1e-6
+    for child, parent in (("inner", "outer"), ("innermost", "inner"),
+                          ("sibling", "outer")):
+        assert ph[parent]["t0_ns"] <= ph[child]["t0_ns"]
+        assert ph[child]["t1_ns"] <= ph[parent]["t1_ns"]
+    assert ph["inner"]["t1_ns"] <= ph["sibling"]["t0_ns"]
+    assert sess.stack == []
+
+
+def test_compile_is_a_span_only_inside_a_session():
+    def compiles(fn_name, sess):
+        return [e for e in sess.events if e["kind"] == "phase"
+                and e["name"] == "jax.compile"
+                and fn_name in e["fun_name"]]
+
+    def obs_compile_probe_a(x):
+        return x * 3.0 + 17.25
+
+    def obs_compile_probe_b(x):
+        return x * 5.0 - 17.25
+
+    x = np.arange(57, dtype=np.float32)
+    with obs.session() as sess:
+        before = time.time_ns()
+        with obs.scope("outer"):
+            jax.jit(obs_compile_probe_a)(x).block_until_ready()
+        spans = compiles("obs_compile_probe_a", sess)
+        assert len(spans) == 1
+        (span,) = spans
+        assert span["parent"] == "outer"
+        assert before <= span["t0_ns"] <= span["t1_ns"] <= time.time_ns()
+        assert span["dur_s"] > 0
+    n_events = len(sess.events)
+    jax.jit(obs_compile_probe_b)(x).block_until_ready()
+    assert len(sess.events) == n_events
+    assert not compiles("obs_compile_probe_b", sess)
+
+
 def test_scalarization_of_array_payloads():
     with obs.session() as sess:
         obs.record("arrays", small=np.arange(3), big=np.zeros(1000),
@@ -132,7 +190,7 @@ def test_retrace_storm_flagged_and_warned():
 
 
 # ------------------------------------------------ jit-safety contract
-def test_jit_tap_stages_nothing_without_session():
+def test_jit_tap_stages_nothing_without_session(monkeypatch):
     # fresh closure per trace: jax caches traces by function identity,
     # which is exactly why the trace-time gate makes sessions have to
     # be entered before the instrumented step is first compiled
@@ -146,6 +204,33 @@ def test_jit_tap_stages_nothing_without_session():
     assert "callback" not in str(jax.make_jaxpr(make_fn())(jnp.ones(4)))
     with obs.session():
         assert "callback" in str(jax.make_jaxpr(make_fn())(jnp.ones(4)))
+
+    # the engine's round: its spans and counters emit nothing without a
+    # session, and the fused step traces to the same program with no
+    # session and under one that streams nothing out of jit
+    emitted = []
+    monkeypatch.setattr(obs.ObsSession, "emit",
+                        lambda self, *a, **k: emitted.append(a))
+    eng = _round_engine()
+    assert eng.engine_cfg.effective_fused
+    step, shapes = eng._fused_step, []
+
+    def spy(*args):
+        shapes.append(jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), args))
+        return step(*args)
+
+    eng._fused_step = spy
+    state = eng.start_run()
+    for t in (1, 2):
+        _one_round(eng, state, t)
+    assert emitted == [] and len(shapes) == 2
+    bare = str(jax.make_jaxpr(eng._build_fused_step_fn())(*shapes[0]))
+    assert "callback" not in bare
+    with obs.session(jit_stream=False):
+        traced = str(jax.make_jaxpr(eng._build_fused_step_fn())(
+            *shapes[0]))
+    assert traced == bare
 
 
 def test_jit_tap_delivers_values_under_jit():
@@ -175,6 +260,108 @@ def test_wire_encode_stages_no_callback_without_session():
     assert "callback" not in str(jax.make_jaxpr(make_agg())(flat, w))
     with obs.session():
         assert "callback" in str(jax.make_jaxpr(make_agg())(flat, w))
+
+
+# ------------------------------------------- spans inside the round
+def _round_engine():
+    """A tiny Table III engine (fused step, bisection-LP power) whose
+    second round of four evaluates and whose first does not."""
+    from repro.sim.scenarios import build_problem
+    from repro.sim.sweep import _make_engine
+
+    scn = _tiny("paper-table3", T=4)
+    eng = _make_engine(scn, build_problem(scn),
+                       ("mixed-resolution", {"lambda_": 0.2, "b": 4}),
+                       "bisection-lp")
+    eng.fl = dataclasses.replace(eng.fl, eval_every=2)
+    return eng
+
+
+def _one_round(eng, state, t):
+    """One round through the public API, as the benchmark drives it."""
+    work = eng.train_round(state, t)
+    sol = eng.solve_uplink_host(state.chan, work.bits_np, work.active)
+    eng.finish_round(state, work, sol.straggler_s,
+                     per_user_s=sol.latencies)
+
+
+def test_engine_round_spans_and_lp_count(monkeypatch):
+    from repro.core.power import bisection_lp
+
+    lps = []
+    feasible = bisection_lp._feasible_powers
+
+    def counted(*args):
+        lps.append(1)
+        return feasible(*args)
+
+    monkeypatch.setattr(bisection_lp, "_feasible_powers", counted)
+    eng = _round_engine()
+    state = eng.start_run()
+    assert eng.power.name == "bisection-lp"
+    assert not eng.eval_due(1) and eng.eval_due(2)
+    with obs.session() as sess:
+        for t in (1, 2):
+            with obs.context(round=t):
+                _one_round(eng, state, t)
+    phases = [e for e in sess.events if e["kind"] == "phase"]
+    order = ["engine.draw", "engine.gather", "engine.h2d",
+             "engine.dispatch", "engine.fetch", "engine.solve"]
+    for t, tail in ((1, []), (2, ["eval"])):
+        names = [e["name"] for e in phases if e["round"] == t
+                 and e["name"] != "jax.compile"]
+        assert names == order + tail, (t, names)
+    # every span of the round is a top-level call of the round's API
+    assert all(e["parent"] is None for e in phases
+               if e["name"] != "jax.compile")
+    # the step compiles in round 1, inside its dispatch
+    assert any(e["parent"] == "engine.dispatch" for e in phases
+               if e["name"] == "jax.compile" and e["round"] == 1)
+    assert lps and sess.counters["phy.lp_solves"] == len(lps)
+    (total,) = [e["total"] for e in sess.events
+                if e["kind"] == "counter" and e["name"] == "phy.lp_solves"]
+    assert total == len(lps)
+    # the report shows the count
+    assert f"phy.lp_solves: {len(lps)}" in render_report(sess.events)
+
+
+def test_report_renders_counter_totals(tmp_path):
+    path = str(tmp_path / "counters.jsonl")
+    with obs.session(jsonl=path):
+        obs.counter("phy.lp_solves", 14)
+        obs.counter("phy.lp_solves", 13)
+        obs.counter("unit.count")
+    events = load_events(path)
+    assert counter_totals(events) == {"phy.lp_solves": 27.0,
+                                      "unit.count": 1.0}
+    text = render_report(events)
+    assert "== counters ==\n  phy.lp_solves: 27\n  unit.count: 1" in text
+    # two sessions' totals in one trace add up; no counters, no section
+    assert counter_totals(events + events)["phy.lp_solves"] == 54.0
+    assert "== counters ==" not in render_report(
+        [e for e in events if e["kind"] != "counter"])
+
+
+def test_phase_breakdown_self_time():
+    def span(name, t0, t1, parent=None):
+        return {"kind": "phase", "name": name, "dur_s": (t1 - t0) * 1e-9,
+                "t0_ns": t0, "t1_ns": t1, "parent": parent}
+
+    events = [span("engine.draw", 10, 20, "train_round"),
+              span("engine.h2d", 30, 60, "train_round"),
+              span("train_round", 0, 100),
+              span("engine.draw", 110, 115, "train_round"),
+              span("train_round", 100, 150),
+              {"kind": "phase", "name": "finish_round", "dur_s": 4e-8}]
+    rows = {r["phase"]: r for r in phase_breakdown(events)}
+    assert rows["train_round"]["total_s"] == pytest.approx(150e-9)
+    assert rows["train_round"]["self_s"] == pytest.approx(105e-9)
+    assert rows["train_round"]["calls"] == 2
+    assert rows["engine.draw"]["self_s"] == pytest.approx(15e-9)
+    assert rows["engine.h2d"]["self_s"] == pytest.approx(30e-9)
+    # no wall-clock times: self time is the total
+    assert rows["finish_round"]["self_s"] == pytest.approx(40e-9)
+    assert "self_s" in render_report(events)
 
 
 # --------------------------------------------------- sim-engine smoke
@@ -326,26 +513,29 @@ def _wire_trace(platform, kind):
             {"kind": "phase", "name": "train_round", "dur_s": 0.5}]
 
 
-@pytest.mark.parametrize("platform,kind,share", [
-    ("tpu", "TPU v5 lite", 20e6 / 819e9),
-    ("cpu", "cpu", None),
+@pytest.mark.parametrize("platform,kind", [
+    ("tpu", "TPU v5 lite"),
+    ("cpu", "cpu"),
 ])
-def test_wire_roofline_share_only_from_a_known_tpu(platform, kind, share):
-    """The HBM roofline share comes from the peaks table of the traced
-    chip; a CPU trace reports the host-clock rate and no share."""
+def test_wire_roofline_share_only_from_a_known_tpu(platform, kind):
+    """The wire rate is bytes over the train phase's host wall time, a
+    host-clock count on every platform: no trace gets a roofline share
+    from it (the kernels' share comes from a profiler trace)."""
     wire = wire_summary(_wire_trace(platform, kind))
     assert wire["wire_gbps"] == pytest.approx(10e6 / 0.5 / 1e9)
-    if share is None:
-        assert "roofline_fraction" not in wire
-    else:
-        assert wire["roofline_fraction"] == pytest.approx(share)
+    assert "roofline_fraction" not in wire
 
 
 def test_unknown_device_kind_has_no_peaks():
     from repro.launch.roofline import device_peaks
     assert device_peaks("TPU v5 lite")["hbm_bw"] == 819e9
     with pytest.raises(KeyError, match="no published peaks"):
-        wire_summary(_wire_trace("tpu", "TPU v99"))
+        device_peaks("TPU v99")
+    # the wire summary reads no peaks table, so an unknown chip is no
+    # error there
+    wire = wire_summary(_wire_trace("tpu", "TPU v99"))
+    assert wire["wire_gbps"] == pytest.approx(10e6 / 0.5 / 1e9)
+    assert "roofline_fraction" not in wire
 
 
 # ------------------------------------- engine verbose / log_every knob

@@ -108,6 +108,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
+import weakref
 from typing import List, NamedTuple, Optional, Tuple
 
 import jax
@@ -558,6 +559,53 @@ _REPL_CHANNEL_SEED_STRIDE = 1 << 20
 # would read as a retrace storm)
 _ENGINE_ORDINAL = [0]
 
+# device copies of training sets: (id(x), id(y), placement) -> (rows, y).
+# Every engine built on the same ImageDataset with the same placement
+# shares one copy (a grid holds one engine per quantizer on one
+# problem); an entry goes when either host array is freed, and holds
+# no reference to them.
+_RESIDENT: dict = {}
+_LANES = 128        # the minor tile of a TPU float32 array
+
+
+def _resident_set(dataset: ImageDataset, sharding=None):
+    """``dataset`` on the device once, uncommitted on the default
+    device or with ``sharding``: its images as float32 rows, one sample
+    per row, zero-padded to a multiple of ``_LANES``, and its labels as
+    int32 (both converted as ``jnp.asarray`` converts them).
+
+    The layout is for the TPU's tiled memory: as ``[N, H, W, C]`` it
+    lays N out along the lanes and every gather first relays out the
+    whole set, and a row that ends inside a tile makes it copy the set
+    too; whole-tile rows are gathered in place.  Counts the bytes of
+    each new copy in ``engine.resident_bytes``."""
+    key = (id(dataset.x), id(dataset.y), sharding)
+    hit = _RESIDENT.get(key)
+    if hit is None:
+        put = jnp.asarray if sharding is None else \
+            (lambda a: jax.device_put(a, sharding))
+        rows = np.reshape(dataset.x, (len(dataset.x), -1))
+        pad = -rows.shape[1] % _LANES
+        if pad:
+            rows = np.pad(rows, ((0, 0), (0, pad)))
+        hit = _RESIDENT[key] = (put(rows), put(dataset.y))
+        for a in (dataset.x, dataset.y):
+            weakref.finalize(a, _RESIDENT.pop, key, None)
+        _obs.counter("engine.resident_bytes",
+                     hit[0].nbytes + hit[1].nbytes)
+    return hit
+
+
+def _gather_minibatches(rows, y, sel, sample_shape):
+    """``x[sel], y[sel]`` from the resident rows: a copy of the values,
+    so the result is the host gather's, bit for bit."""
+    n = int(np.prod(sample_shape))
+    return (rows[sel][..., :n].reshape(sel.shape + sample_shape),
+            y[sel])
+
+
+_gather_default = jax.jit(_gather_minibatches, static_argnums=3)
+
 
 class VectorizedFLEngine:
     """Algorithm 1 with all K users vectorized into one step per round.
@@ -675,6 +723,14 @@ class VectorizedFLEngine:
         _ENGINE_ORDINAL[0] += 1
         self._obs_name = f"engine{_ENGINE_ORDINAL[0]}[{quantizer.name}]"
         self._user_sharding, self._repl_sharding = self._user_shardings()
+        if self._user_sharding is not None:
+            # the training set replicated over the mesh; each device
+            # gathers only its own users' rows
+            self._gather_sharded = jax.jit(
+                _gather_minibatches, static_argnums=3,
+                in_shardings=(self._repl_sharding, self._repl_sharding,
+                              self._user_sharding),
+                out_shardings=(self._user_sharding, self._user_sharding))
         if self.engine_cfg.effective_fused:
             self._train_flat = None
             self._fused_step_fn = self._build_fused_step_fn()
@@ -1523,7 +1579,7 @@ class VectorizedFLEngine:
             active = self._draw_active(state.part_rng)
         if self._clusters > 1 and not ecfg.async_active:
             # two-level hierarchy: only one cluster's minibatches are
-            # transferred (and resident) at a time
+            # gathered (and resident) at a time
             return self._clustered_round(state, t, sel, active)
         faults = self._draw_faults(t)
         extra = () if faults is None else (faults,)
@@ -1558,13 +1614,14 @@ class VectorizedFLEngine:
                              quarantined=quarantined)
         weights = self._round_weights(active)
         if not ecfg.effective_fused:
-            xs, ys = self._minibatches(sel)
+            xs, ys = self._minibatches(sel, user_sharded=True)
             with _obs.scope("engine.dispatch"):
                 state.params, state.qstate, bits, aux = \
                     self._dense_round(state.params, state.qstate, xs, ys,
                                       weights, active)
         else:
-            xs, ys, w_dev, a_dev = self._minibatches(sel, weights, active)
+            xs, ys, w_dev, a_dev = self._minibatches(
+                sel, weights, active, user_sharded=True)
             with _obs.scope("engine.dispatch"):
                 state.params, state.qstate, bits, aux = self._fused_step(
                     state.params, state.qstate, xs, ys, w_dev, a_dev,
@@ -1577,17 +1634,32 @@ class VectorizedFLEngine:
         return RoundWork(t=t, bits_np=bits_np, active=active,
                          mean_s=mean_s, quarantined=quarantined)
 
-    def _minibatches(self, sel: np.ndarray, *vectors: np.ndarray):
-        """The minibatches that ``sel`` indexes, gathered on the host
-        (``engine.gather``) and moved to the device with the round's
-        per-user ``vectors`` as float32 (``engine.h2d``).  On a TPU
-        ``jnp.asarray`` returns before the transfer ends, so the span
-        covers its start; the step waits for the rest, and the host
-        with it in ``engine.fetch``."""
+    def _minibatches(self, sel: np.ndarray, *vectors: np.ndarray,
+                     user_sharded: bool = False):
+        """The minibatches that ``sel`` indexes, gathered on the device
+        from the resident training set (``engine.gather``: the indices'
+        transfer as int32 and the gather's dispatch), and the round's
+        per-user ``vectors`` moved to the device as float32
+        (``engine.h2d``).  ``engine.h2d_bytes`` counts what crosses to
+        the device: the indices and the vectors, not the images.
+
+        ``user_sharded``: the caller's step takes the users in the
+        engine's user sharding (the sync step, the exact path).  Under
+        a mesh the set is then replicated over it and the minibatches
+        come out user-sharded, each device gathering its own users'
+        rows; otherwise they sit uncommitted on the default device."""
+        sel = np.asarray(sel, np.int32)
+        _obs.counter("engine.h2d_bytes",
+                     sel.nbytes + 4 * sum(np.size(v) for v in vectors))
+        if user_sharded and self._user_sharding is not None:
+            gather, placement = self._gather_sharded, self._repl_sharding
+        else:
+            gather, placement = _gather_default, None
         with _obs.scope("engine.gather"):
-            x, y = self.dataset.x[sel], self.dataset.y[sel]
+            xs, ys = gather(*_resident_set(self.dataset, placement), sel,
+                            self.dataset.x.shape[1:])
         with _obs.scope("engine.h2d"):
-            return (jnp.asarray(x), jnp.asarray(y),
+            return (xs, ys,
                     *(jnp.asarray(v, jnp.float32) for v in vectors))
 
     def _fetch(self, bits, aux) -> Tuple[np.ndarray, np.ndarray]:
@@ -1603,7 +1675,7 @@ class VectorizedFLEngine:
                          active: np.ndarray) -> RoundWork:
         """Two-level hierarchy (WirePath.clusters > 1, DESIGN.md §12):
         the K users are split host-side into contiguous AP-cluster
-        groups; each group's minibatches are transferred alone and its
+        groups; each group's minibatches are gathered alone and its
         cohort scan produces a partial [d] aggregate on device.  The
         partials combine in fixed cluster order (one tiny dispatch per
         hop) before a single param-update dispatch — neither a [K, d]

@@ -38,8 +38,9 @@ def readings(workload: str, seeds, control_seeds: int = 3,
     import gen
     import reference
 
-    ref = reference.Reference(cfg, "highest")
-    ctrl = reference.Reference(cfg, "high")
+    model = gen.model(cfg)
+    ref = reference.Reference(cfg, model, "highest")
+    ctrl = reference.Reference(cfg, model, "high")
     faults = ["half_users"]
     if cfg.get("M") is not None:
         faults.append("full_power")

@@ -37,7 +37,8 @@ users:
 
 In each round, leaves whose reference update is under a thousandth of
 the median leaf's are left out of the model numbers: they move by
-round-off alone.
+round-off alone.  A model is any parameter pytree; its leaves are
+matched by key path (``['conv_w']``, ``['out']['w']``).
 """
 import math
 from typing import Dict, List
@@ -49,6 +50,14 @@ def starts(got: dict, params0: dict) -> List[dict]:
     """The model each checked round starts from: ``w_0``, then the
     program's own model after the round before."""
     return [params0] + list(got["params"][:-1])
+
+
+def leaves(params) -> Dict[str, np.ndarray]:
+    """A parameter pytree's leaves by key path."""
+    import jax
+
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    return {jax.tree_util.keystr(path): v for path, v in flat}
 
 
 def _f64(a) -> np.ndarray:
@@ -93,7 +102,8 @@ def compare(got: dict, want: dict, params0: dict, b: int
     if want["latency"]:
         out["latency_gap"] = 0.0
     for t, base in enumerate(starts(got, params0)):
-        gap, diff = _update_numbers(gp[t], wp[t], base)
+        gap, diff = _update_numbers(leaves(gp[t]), leaves(wp[t]),
+                                    leaves(base))
         gb, wb = got["bits"][t], want["bits"][t]
         per_user = np.round(np.abs(_f64(gb) - _f64(wb)) / (b - 1))
         now = {"update_gap": gap, "update_diff": diff,

@@ -1,4 +1,5 @@
-"""Training operations of the paper's CNN per sample, from its shapes.
+"""Training operations of the paper's CNN per sample, from its shapes
+(``models/paper_cnn.py``).
 
 Forward and backward of one sample: the 3x3 valid convolution (forward
 and weight gradient; the input needs no gradient), the first dense
@@ -7,16 +8,18 @@ layer (forward, weight gradient and input gradient) and the second
 activations, pooling and the optimizer's elementwise update are left
 out: they are not matrix work.
 """
+import numpy as np
+
+from models import paper_cnn
 
 
 def per_sample(cfg: dict) -> int:
-    hw, ch, nf = cfg["input_hw"], cfg["channels"], cfg["conv_filters"]
-    du, nc = cfg["dense_units"], cfg["n_classes"]
-    conv_out = (hw - 2) ** 2 * nf
-    conv = 2 * conv_out * 3 * 3 * ch
-    flat = ((hw - 2) // 2) ** 2 * nf
-    dense1 = 2 * flat * du
-    dense2 = 2 * du * nc
+    s = paper_cnn.cnn_shapes(cfg)
+    kh, kw, _, _ = s["conv_w"]
+    positions = (cfg["input_hw"] - kh + 1) * (cfg["input_hw"] - kw + 1)
+    conv = 2 * positions * int(np.prod(s["conv_w"]))
+    dense1 = 2 * int(np.prod(s["dense1_w"]))
+    dense2 = 2 * int(np.prod(s["dense2_w"]))
     return 2 * conv + 3 * dense1 + 3 * dense2
 
 

@@ -1,11 +1,13 @@
 """The one generator of the benchmark's inputs, driven by ``--seed``.
 
 Everything the program under test receives is made here from the seed
-and from the cell's configuration and traffic files: the synthetic
-image task, the user partition, the positions of the access points and
-users, the initial parameters and the seed of the minibatch stream.
-The plain reference (``reference.py``) gets the same inputs, so the
-two runs share no state that the program made.
+and from the cell's configuration and traffic files: the user
+partition, the positions of the access points and users and the seed
+of the minibatch stream, and, through the configuration's model module
+(``models/<model>.py``), the data and the initial parameters, each
+from a sub-seed of its own.  The plain reference (``reference.py``)
+gets the same inputs, so the two runs share no state that the program
+made.
 
 Every seed gives the same amount of work: the same shapes, the same
 number of users, every shard at least one minibatch long, and one
@@ -16,21 +18,21 @@ problem have the same size whatever the seed.
 from __future__ import annotations
 
 import dataclasses
-from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional
+import importlib
+from typing import Any, List, Optional
 
 import numpy as np
 
 
 @dataclasses.dataclass
 class Inputs:
-    x: np.ndarray                 # [n_train + n_test, H, W, C] f32
-    y: np.ndarray                 # [n_train + n_test] int64
+    x: np.ndarray                 # [n_train + n_test, ...] samples
+    y: np.ndarray                 # [n_train + n_test, ...] targets
     n_train: int
     shards: List[np.ndarray]      # K sorted index arrays into x[:n_train]
     ap_pos: Optional[np.ndarray]  # [M, 2] metres, None without a channel
     user_pos: Optional[np.ndarray]  # [K, 2]
-    params0: Dict[str, np.ndarray]  # initial model, host copy
+    params0: Any                  # initial parameter pytree, host copy
     mb_seed: int                  # seed of the minibatch stream
 
 
@@ -39,51 +41,6 @@ def sub_seeds(seed: int) -> np.ndarray:
     parameter and minibatch streams.  Accepts any non-negative int."""
     ss = np.random.SeedSequence(int(seed))
     return ss.generate_state(5, np.uint32) >> 1
-
-
-NOISE_CHUNKS = 16      # fixed, so the images do not depend on the threads
-NOISE_THREADS = 4
-
-
-def make_images(n: int, hw: int, channels: int, n_classes: int,
-                noise: float, seed: int):
-    """Class-conditional low-frequency templates, shifted by up to 3
-    pixels per sample, plus Gaussian noise, scaled to [0, 1] — the
-    repository's synthetic image task, drawn in bulk: each image is one
-    row of a table of every template at every shift, and the noise is
-    drawn in fixed chunks, each from its own stream, by a few threads
-    (numpy's generators release the GIL while they fill)."""
-    rng = np.random.default_rng(seed)
-    yy, xx = np.mgrid[0:hw, 0:hw].astype(np.float32) / hw
-    f = rng.uniform(1.0, 4.0, (n_classes, 2)).astype(np.float32)
-    ph = rng.uniform(0, 2 * np.pi, (n_classes, 2)).astype(np.float32)
-    base = (np.sin(2 * np.pi * f[:, 0, None, None] * xx + ph[:, 0, None, None])
-            * np.cos(2 * np.pi * f[:, 1, None, None] * yy
-                     + ph[:, 1, None, None]))
-    gain = rng.uniform(0.3, 1.0, (n_classes, channels)).astype(np.float32)
-    templates = base[..., None] * gain[:, None, None, :]   # [C, H, W, ch]
-    y = rng.integers(0, n_classes, n)
-    shifts = rng.integers(-3, 4, (n, 2))
-    # table[c, a + 3, b + 3][r, s] = templates[c, (r - a) % hw, (s - b) % hw]
-    table = np.stack([np.stack([np.roll(templates, (a, b), axis=(1, 2))
-                                for b in range(-3, 4)], 1)
-                      for a in range(-3, 4)], 1)   # [C, 7, 7, H, W, ch]
-    x = table[y, shifts[:, 0] + 3, shifts[:, 1] + 3]
-    streams = np.random.SeedSequence(seed).spawn(NOISE_CHUNKS)
-    cuts = np.linspace(0, n, NOISE_CHUNKS + 1).astype(int)
-
-    def add_noise(i):
-        part = x[cuts[i]:cuts[i + 1]]
-        z = np.random.default_rng(streams[i]).standard_normal(
-            part.shape, dtype=np.float32)
-        z *= noise
-        part += z
-
-    with ThreadPoolExecutor(NOISE_THREADS) as pool:
-        list(pool.map(add_noise, range(NOISE_CHUNKS)))
-    x -= x.min()
-    x /= x.max() + 1e-9
-    return x.astype(np.float32, copy=False), y.astype(np.int64)
 
 
 def partition(y: np.ndarray, K: int, kind: str, alpha: float,
@@ -122,46 +79,19 @@ def positions(M: int, K: int, area_m: float, seed: int):
     return ap, users
 
 
-def cnn_shapes(cfg: dict) -> Dict[str, tuple]:
-    hw, ch, nf, du, nc = (cfg["input_hw"], cfg["channels"],
-                          cfg["conv_filters"], cfg["dense_units"],
-                          cfg["n_classes"])
-    flat = ((hw - 2) // 2) ** 2 * nf
-    return {"conv_w": (3, 3, ch, nf), "conv_b": (nf,),
-            "dense1_w": (flat, du), "dense1_b": (du,),
-            "dense2_w": (du, nc), "dense2_b": (nc,)}
-
-
-def init_params(cfg: dict, seed: int):
-    """He-style normal weights over fan-in, zero biases, made on the
-    device in one jitted call and returned as float32 device arrays."""
-    import jax
-    import jax.numpy as jnp
-
-    shapes = cnn_shapes(cfg)
-
-    @jax.jit
-    def make(key):
-        out = {}
-        for i, (name, shp) in enumerate(sorted(shapes.items())):
-            if name.endswith("_b"):
-                out[name] = jnp.zeros(shp, jnp.float32)
-            else:
-                fan = int(np.prod(shp[:-1]))
-                out[name] = (jax.random.normal(jax.random.fold_in(key, i),
-                                               shp, jnp.float32)
-                             / np.float32(np.sqrt(fan)))
-        return out
-
-    return make(jax.random.PRNGKey(int(seed)))
+def model(cfg: dict):
+    """The configuration's model module, ``models/<model>.py`` with
+    ``-`` read as ``_`` (see ``models/__init__.py``)."""
+    return importlib.import_module(
+        "models." + cfg["model"].replace("-", "_"))
 
 
 def make_inputs(cfg: dict, seed: int) -> Inputs:
     s_data, s_part, _, s_init, s_mb = (int(v) for v in sub_seeds(seed))
-    n_train, n_test = cfg["n_train"], cfg["n_test"]
-    x, y = make_images(n_train + n_test, cfg["input_hw"], cfg["channels"],
-                       cfg["n_classes"], cfg["image_noise"], s_data)
-    shards = partition(y[:n_train], cfg["K"], cfg["partition"],
+    mod = model(cfg)
+    n_train = cfg["n_train"]
+    x, y, strata = mod.make_data(cfg, s_data)
+    shards = partition(strata[:n_train], cfg["K"], cfg["partition"],
                        cfg.get("dirichlet_alpha", 0.0), cfg["batch_size"],
                        s_part)
     ap = users = None
@@ -171,7 +101,7 @@ def make_inputs(cfg: dict, seed: int) -> Inputs:
         ap, users = positions(cfg["M"], cfg["K"], cfg["area_m"],
                               cfg["geometry_seed"])
     import jax
-    params0 = jax.device_get(init_params(cfg, s_init))
+    params0 = jax.device_get(mod.init_params(cfg, s_init))
     return Inputs(x=x, y=y, n_train=n_train, shards=shards, ap_pos=ap,
                   user_pos=users, params0=params0, mb_seed=s_mb)
 

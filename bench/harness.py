@@ -10,6 +10,13 @@ compiles the round step) and keeps a host copy of what each produced;
 the window then goes on from there.  Once the window has closed and the
 program's state is freed, ``reference.py`` follows the same rounds,
 each from the program's model before it, and ``check.py`` compares.
+
+Nothing here knows the model: the configuration's ``model`` names a
+module, ``models/<model>.py`` (``gen.model``), that supplies the data,
+the initial parameters, the engine's datasets and ``ModelSpec``, and
+the reference's loss.  The traced run (``--trace 1``) also opens a
+``repro.obs`` session around its rounds and hands the program's phase
+spans and counter totals to the per-layer readers.
 """
 from __future__ import annotations
 
@@ -113,28 +120,18 @@ def device_info(devs) -> dict:
 # ----------------------------------------------------------- engine
 def build_engine(cfg: dict, traffic: dict, inputs):
     """The engine ``run_cell`` builds for this configuration, on the
-    seed's inputs: the scenario's EngineConfig (with the traffic's
-    plane and mesh), FLConfig, quantizer and power controller."""
-    import jax.numpy as jnp
-
+    seed's inputs: the model module's datasets and ModelSpec, the
+    scenario's EngineConfig (with the traffic's plane and mesh),
+    FLConfig, quantizer and power controller."""
+    import gen
     from repro.core.channel import CFmMIMOConfig, make_channel
     from repro.core.power import make_power_controller
     from repro.core.quantize import make_quantizer
-    from repro.data.synthetic import ImageDataset
-    from repro.fl.cnn import cnn_accuracy, cnn_loss
     from repro.fl.loop import FLConfig
-    from repro.fl.models import ModelSpec
     from repro.sim.engine import VectorizedFLEngine
     from repro.sim.scenarios import Scenario
 
-    n = inputs.n_train
-    train = ImageDataset(x=inputs.x[:n], y=inputs.y[:n],
-                         n_classes=cfg["n_classes"])
-    test = ImageDataset(x=inputs.x[n:], y=inputs.y[n:],
-                        n_classes=cfg["n_classes"])
-    params0 = {k: jnp.asarray(v) for k, v in inputs.params0.items()}
-    model = ModelSpec(name=cfg["model"], init=lambda key: params0,
-                      loss=cnn_loss, accuracy=cnn_accuracy)
+    train, test, model = gen.model(cfg).engine_inputs(cfg, inputs)
     chan = None
     if cfg.get("M") is not None:
         net = CFmMIMOConfig(
@@ -263,9 +260,14 @@ def run(opts: Options, t_start: float) -> dict:
     t = CHECKED_ROUNDS
     result: Dict = {}
     if opts.trace:
+        from repro import obs
+
         n_traced = traffic["traced_rounds"]
         spans: list = []
-        with tempfile.TemporaryDirectory() as tdir:
+        # the program's spans and counters; no in-jit taps, so the step
+        # already compiled runs as it is
+        with obs.session(jit_stream=False) as sess, \
+                tempfile.TemporaryDirectory() as tdir:
             # device activity only: the host tracer records every chunk
             # of the host's relayout of the minibatches and triples the
             # round's time; the harness keeps its own spans instead
@@ -283,11 +285,13 @@ def run(opts: Options, t_start: float) -> dict:
             import xplane
 
             red = xplane.reduce(xplane.find(tdir), len(devs), spans)
+        phases = [e for e in sess.events if e["kind"] == "phase"]
         attempted = n_traced
         result["trace"] = red
-        result["traced_rounds"] = n_traced
-        result["traced_host_s"] = w1 - w0
-        log(f"bench: traced {n_traced} rounds in {w1 - w0:.3f} s")
+        log(f"bench: traced {n_traced} rounds in {w1 - w0:.3f} s; "
+            f"{len(phases)} program spans, "
+            f"{sum(e['name'] == 'jax.compile' for e in phases)} of them "
+            "jax.compile")
     else:
         times: List[float] = []
         w0 = time.perf_counter()
@@ -325,15 +329,17 @@ def run(opts: Options, t_start: float) -> dict:
                                  cfg["batch_size"], inputs.mb_seed,
                                  CHECKED_ROUNDS)
     r0 = time.perf_counter()
-    numbers = check.against_reference(reference.Reference(cfg), inputs,
-                                      sels, got, cfg["b"])
+    numbers = check.against_reference(
+        reference.Reference(cfg, gen.model(cfg)), inputs, sels, got,
+        cfg["b"])
     correct, checks = check.verdict(numbers, limits)
     log(f"bench: reference {time.perf_counter() - r0:.3f} s")
 
     if opts.trace:
         ctx = {"cfg": cfg, "traffic": traffic, "cell": cell, "d": d,
                "K": K, "chips": len(devs), "kind": devs[0].device_kind,
-               "rounds": attempted, "trace": result["trace"]}
+               "rounds": attempted, "trace": result["trace"],
+               "spans": phases, "counters": sess.counters}
         metrics = {}
         for m in per_layer_metrics(spec, opts.workload):
             mod = importlib.import_module(f"metrics.{m['name']}")

@@ -3,19 +3,24 @@
 Written from the paper (arXiv 2412.10878, Algorithm 1, eqs. 2-8 and
 12-14) and its Table I network, and importing nothing of the program:
 
-* the paper's CNN — Conv(32, 3x3) + ReLU, MaxPool 2x2, Dense(64) + ReLU,
-  Dense(n_classes) — and its softmax cross-entropy;
-* L local AdaGrad steps per user from the round's global model (eq. 2);
+* L local AdaGrad steps per user from the round's global model (eq. 2),
+  on the loss of the configuration's model module (``models/<model>.py``,
+  given to ``Reference``: the model's part of the reference), over any
+  parameter pytree;
 * adaptive mixed-resolution quantization of each user's delta (eqs.
   6-8) with its payload bits ``d (b s + 1 - s) + 32``;
-* the rho-weighted sum of the reconstructions added to the model (eq. 3);
+* the rho-weighted sum of the reconstructions added to the model (eq. 3),
+  folded ``FOLD_BLOCK`` users at a time in user order, as the model
+  module says (all K in one sum where it says None), so that a model
+  whose ``[K, d]`` stack of deltas does not fit needs no edit here;
 * the cell-free MIMO coefficients of eq. 5 from the AP and user
   positions, and the min-max-latency power control: bisection over the
   rate-per-bit target with an LP feasibility test (eq. 14), giving each
   user's uplink latency ``bits / rate`` (eq. 12).
 
 Everything is float32.  ``precision="highest"`` computes every matrix
-product (the convolution is one, over the image's patches) at
+product (the model module routes each through ``mm``, the CNN's
+convolution as a product over the image's patches) at
 ``Precision.HIGHEST``: the reference.  ``precision="high"`` is the
 control, the nearest precision below the configuration's float32 at
 ``highest``: each product in three bf16 passes with float32
@@ -36,11 +41,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-LEAVES = ("conv_b", "conv_w", "dense1_b", "dense1_w", "dense2_b",
-          "dense2_w")
 
-
-# ------------------------------------------------------------ model
+# -------------------------------------------------------- precision
 def matmul(precision: str):
     """A float32 matrix product, forward and backward, at ``precision``:
     ``"highest"`` (``Precision.HIGHEST``) or ``"high"`` (three bf16
@@ -90,55 +92,31 @@ def matmul(precision: str):
     return f
 
 
-def forward(p, x, mm):
-    """The paper's CNN on x [B, H, W, C]: the 3x3 valid convolution as
-    a product of the image's patches with the kernel, ReLU, 2x2 max
-    pooling, then the two dense layers."""
-    import jax.numpy as jnp
-
-    B, H, W, C = x.shape
-    kh, kw, _, F = p["conv_w"].shape
-    Ho, Wo = H - kh + 1, W - kw + 1
-    patches = jnp.concatenate([x[:, i:i + Ho, j:j + Wo, :]
-                               for i in range(kh) for j in range(kw)],
-                              axis=-1)                  # [B, Ho, Wo, 9C]
-    h = mm(patches.reshape(-1, kh * kw * C),
-           p["conv_w"].reshape(kh * kw * C, F)).reshape(B, Ho, Wo, F)
-    h = jnp.maximum(h + p["conv_b"], 0)
-    h = h.reshape(B, Ho // 2, 2, Wo // 2, 2, F).max(axis=(2, 4))
-    h = jnp.maximum(mm(h.reshape(B, -1), p["dense1_w"]) + p["dense1_b"], 0)
-    return mm(h, p["dense2_w"]) + p["dense2_b"]
-
-
-def loss(p, x, y, mm):
-    import jax
-    import jax.numpy as jnp
-
-    logp = jax.nn.log_softmax(forward(p, x, mm))
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
-
-
-def _local_fn(L: int, alpha: float, precision: str):
-    """jit(params, xs [K, L, b, ...], ys [K, L, b]) -> deltas [K, d]:
-    each user's L AdaGrad steps from the round's model (eq. 2)."""
+def _local_fn(loss, alpha: float, precision: str):
+    """jit(params, xs [B, L, b, ...], ys [B, L, ...]) -> deltas [B, d]:
+    each user's L AdaGrad steps from the round's model (eq. 2), the
+    delta flattened leaf by leaf in the pytree's order."""
     import jax
     import jax.numpy as jnp
 
     mm = matmul(precision)
+    tmap = jax.tree_util.tree_map
 
     def one_user(p, xs, ys):
-        g0 = {k: jnp.zeros_like(v) for k, v in p.items()}
+        g0 = tmap(jnp.zeros_like, p)
 
         def step(carry, batch):
             w, acc = carry
             grads = jax.grad(loss)(w, batch[0], batch[1], mm)
-            acc = {k: acc[k] + grads[k] * grads[k] for k in w}
-            w = {k: w[k] - alpha / jnp.sqrt(acc[k] + 1e-8) * grads[k]
-                 for k in w}
+            acc = tmap(lambda a, g: a + g * g, acc, grads)
+            w = tmap(lambda v, a, g: v - alpha / jnp.sqrt(a + 1e-8) * g,
+                     w, acc, grads)
             return (w, acc), None
 
         (w, _), _ = jax.lax.scan(step, (p, g0), (xs, ys))
-        return jnp.concatenate([jnp.ravel(w[k] - p[k]) for k in LEAVES])
+        leaves = jax.tree_util.tree_leaves
+        return jnp.concatenate([jnp.ravel(a - b)
+                                for a, b in zip(leaves(w), leaves(p))])
 
     return jax.jit(jax.vmap(one_user, in_axes=(None, 0, 0)))
 
@@ -246,23 +224,45 @@ def latencies(net: dict, coef, bits: np.ndarray, p: np.ndarray):
 
 # ------------------------------------------------------------ rounds
 class Reference:
-    """The reference rounds of one configuration at one precision; its
-    compiled functions are kept for every seed it is run on."""
+    """The reference rounds of one configuration's model (its model
+    module, ``model``) at one precision; its compiled functions are
+    kept for every seed it is run on."""
 
-    def __init__(self, cfg: dict, precision: str = "highest"):
+    def __init__(self, cfg: dict, model, precision: str = "highest"):
         import jax
         import jax.numpy as jnp
 
         self.cfg = cfg
-        self.local = _local_fn(cfg["L"], cfg["lr"], precision)
+        self.fold_block = model.FOLD_BLOCK
+        self.local = _local_fn(model.loss, cfg["lr"], precision)
         lam, b = cfg["lambda_"], cfg["b"]
         self.quantize = jax.jit(jax.vmap(lambda d: quantize(d, lam, b)))
         # the weighted sum in user order, elementwise in float32
         self.aggregate = jax.jit(lambda w, r: jnp.sum(w[:, None] * r,
                                                       axis=0))
 
+    def fold(self, p, inputs, sel: np.ndarray, rho: np.ndarray):
+        """One round's users from the model ``p``: (the rho-weighted sum
+        of their reconstructions, each user's payload bits), the users
+        trained, quantized and summed the model module's ``FOLD_BLOCK``
+        at a time, the blocks' sums added in user order."""
+        import jax.numpy as jnp
+
+        K = len(sel)
+        block = self.fold_block or K
+        agg, bits = None, []
+        for i in range(0, K, block):
+            j = min(i + block, K)
+            delta = self.local(p, jnp.asarray(inputs.x[sel[i:j]]),
+                               jnp.asarray(inputs.y[sel[i:j]]))
+            recon, b = self.quantize(delta)
+            part = self.aggregate(jnp.asarray(rho[i:j], jnp.float32), recon)
+            agg = part if agg is None else agg + part
+            bits.append(np.asarray(b, np.float64))
+        return agg, np.concatenate(bits)
+
     def run(self, inputs, sels: List[np.ndarray],
-            starts: Optional[List[dict]] = None,
+            starts: Optional[List] = None,
             fault: Optional[str] = None, shards_over: int = 1
             ) -> Dict[str, list]:
         """The first ``len(sels)`` rounds from ``inputs.params0``: per
@@ -273,6 +273,7 @@ class Reference:
         import jax
         import jax.numpy as jnp
 
+        tree = jax.tree_util
         cfg = self.cfg
         sizes = np.array([len(s) for s in inputs.shards], np.float64)
         rho = sizes / sizes.sum()
@@ -282,30 +283,32 @@ class Reference:
             rho = rho / rho.sum()
         elif fault == "no_exchange":
             rho = np.where(np.arange(K) < K // shards_over, rho, 0.0)
-        shapes = {k: np.shape(inputs.params0[k]) for k in LEAVES}
-        cuts = np.cumsum([int(np.prod(shapes[k])) for k in LEAVES])[:-1]
+        treedef = tree.tree_structure(inputs.params0)
+        shapes = [np.shape(v) for v in tree.tree_leaves(inputs.params0)]
+        cuts = np.cumsum([int(np.prod(s)) for s in shapes])[:-1]
         coef = None
         if cfg.get("M") is not None:
             coef = channel_coefficients(cfg, inputs.ap_pos,
                                         inputs.user_pos)
-        p = {k: jnp.asarray(inputs.params0[k], jnp.float32)
-             for k in LEAVES}
+
+        def f32(params):
+            return tree.tree_map(lambda v: jnp.asarray(v, jnp.float32),
+                                 params)
+
+        p = f32(inputs.params0)
         out = {"params": [], "bits": [], "latency": []}
         for t, sel in enumerate(sels):
             if starts is not None:
-                p = {k: jnp.asarray(starts[t][k], jnp.float32)
-                     for k in LEAVES}
-            delta = self.local(p, jnp.asarray(inputs.x[sel]),
-                               jnp.asarray(inputs.y[sel]))
-            recon, bits = self.quantize(delta)
-            agg = self.aggregate(jnp.asarray(rho, jnp.float32), recon)
+                p = f32(starts[t])
+            agg, bits = self.fold(p, inputs, sel, rho)
             if fault != "frozen":
                 parts = jnp.split(agg, cuts)
-                p = {k: p[k] + parts[i].reshape(shapes[k])
-                     for i, k in enumerate(LEAVES)}
-            bits = np.maximum(np.asarray(bits, np.float64), 1.0)
-            out["params"].append({k: np.asarray(v, np.float32)
-                                  for k, v in jax.device_get(p).items()})
+                p = tree.tree_unflatten(treedef, [
+                    v + part.reshape(shp) for v, part, shp
+                    in zip(tree.tree_leaves(p), parts, shapes)])
+            bits = np.maximum(bits, 1.0)
+            out["params"].append(tree.tree_map(
+                lambda v: np.asarray(v, np.float32), jax.device_get(p)))
             out["bits"].append(bits)
             if coef is not None:
                 pw = (np.ones(K) if fault == "full_power" else

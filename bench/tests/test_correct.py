@@ -119,8 +119,8 @@ def test_control_is_not_correct(workload):
     import reference
 
     _, cfg, _, limits, _ = harness.load_cell(workload)
-    ref = reference.Reference(cfg, "highest")
-    ctrl = reference.Reference(cfg, "high")
+    ref = reference.Reference(cfg, gen.model(cfg), "highest")
+    ctrl = reference.Reference(cfg, gen.model(cfg), "high")
     for seed in (SEED, 7, 12345):
         inputs = gen.make_inputs(cfg, seed)
         sels = gen.minibatch_indices(inputs.shards, cfg["L"],

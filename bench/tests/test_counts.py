@@ -26,11 +26,12 @@ def test_training_flops(name, per_sample, per_round):
 @pytest.mark.parametrize("name", ["cnn-cifar10-table3",
                                   "cnn-fmnist-table2"])
 def test_config_params_match_shapes(name):
-    import gen
+    import numpy as np
+
+    from models import paper_cnn
 
     c = cfg(name)
-    n = sum(int(__import__("numpy").prod(s))
-            for s in gen.cnn_shapes(c).values())
+    n = sum(int(np.prod(s)) for s in paper_cnn.cnn_shapes(c).values())
     assert n == c["params"]
 
 

@@ -7,6 +7,7 @@ import pytest
 
 import gen
 from conftest import BENCH, TINY
+from models import paper_cnn
 
 
 def cfg(name, **over):
@@ -41,7 +42,7 @@ def test_every_seed_same_work(seed):
     assert inp.x.shape == (8016, 32, 32, 3) and inp.x.dtype == np.float32
     assert 0.0 <= inp.x.min() and inp.x.max() <= 1.0
     shapes = {k: v.shape for k, v in inp.params0.items()}
-    assert shapes == gen.cnn_shapes(c)
+    assert shapes == paper_cnn.cnn_shapes(c)
 
 
 def test_minibatches_follow_algorithm_1_draw_order():

@@ -1,4 +1,4 @@
-"""The paper's power control: bisection over eta + LP feasibility (§III).
+"""The paper's power control: bisection over eta + feasibility (§III).
 
 Problem (14): maximize eta s.t.  0 <= p <= 1  and for every user j
 
@@ -8,15 +8,19 @@ Problem (14): maximize eta s.t.  0 <= p <= 1  and for every user j
 For fixed eta the constraints are linear in p, so feasibility is an LP;
 bisection over eta converges to the global optimum within eps_B
 (Algorithm 1, lines 13-23).  We recover the power vector of the last
-feasible eta.  scipy.optimize.linprog (HiGHS) solves the feasibility
-program with objective min sum(p) — any feasible point works; minimum
-total power is a natural tie-break and matches how such LPs are run in
-practice.
+feasible eta.  The LP has a closed answer (DESIGN.md §7): with every
+target attainable (A_bar_j - theta_j B_bar_j > 0), constraint (14c)
+reads p >= M p + c with M >= 0, c > 0, a set that is non-empty iff
+rho(M) < 1; its componentwise-least element p* = (I - M)^{-1} c is the
+min-sum-power point the LP would return.  So each feasibility test is
+one K x K float64 linear solve, feasible iff p* is finite, positive and
+inside the box: the oracle of the batched JAX path (repro.phy.solvers)
+too.  The HiGHS LP stays as the independent check, in
+tests/test_power_oracle.py and bench/reference.py.
 """
 from __future__ import annotations
 
 import numpy as np
-from scipy.optimize import linprog
 
 from repro import obs
 
@@ -25,24 +29,21 @@ from .base import PowerController, PowerSolution
 
 
 def _feasible_powers(chan: ChannelRealization, theta: np.ndarray):
-    """LP feasibility of (14c) for fixed theta; returns p or None."""
-    K = theta.shape[0]
-    # constraint rows: -(A_j - th_j B_j) p_j + th_j sum_{j'} Bt[j,j'] p_j'
-    #                  <= -th_j I_M_j
-    # Rows are normalized by th_j * I_M_j (RHS = -1): the raw coefficients
-    # are O(1e-12) — far below the LP solver's absolute feasibility
-    # tolerance, which would make every theta look "feasible".
-    A_ub = theta[:, None] * chan.B_tilde.copy()
-    diag = -(chan.A_bar - theta * chan.B_bar)
-    A_ub[np.arange(K), np.arange(K)] = diag
-    scale = theta * chan.I_M
-    if np.any(scale <= 0) or not np.all(np.isfinite(A_ub)):
+    """Feasibility of (14c) for fixed theta: the least fixed point of
+    p = M p + c if it lies in the box, else None."""
+    denom = chan.A_bar - theta * chan.B_bar
+    if np.any(denom <= 0):          # target unattainable at any power
         return None
-    A_ub = A_ub / scale[:, None]
-    b_ub = -np.ones(K)
-    res = linprog(c=np.ones(K), A_ub=A_ub, b_ub=b_ub,
-                  bounds=[(0.0, 1.0)] * K, method="highs")
-    return res.x if res.status == 0 else None
+    row = theta / denom
+    M = row[:, None] * chan.B_tilde
+    np.fill_diagonal(M, 0.0)        # j' != j: the diagonal is unused
+    c = row * chan.I_M
+    try:
+        p = np.linalg.solve(np.eye(theta.shape[0]) - M, c)
+    except np.linalg.LinAlgError:
+        return None
+    ok = np.all(np.isfinite(p)) and np.all(p > 0) and np.all(p <= 1.0)
+    return p if ok else None
 
 
 def eta_upper_bound(chan: ChannelRealization, bits: np.ndarray) -> float:
@@ -64,6 +65,8 @@ class BisectionLPPowerControl(PowerController):
 
     def solve(self, chan: ChannelRealization, bits: np.ndarray
               ) -> PowerSolution:
+        """Bisect eta; counts its feasibility tests, one linear solve
+        per midpoint past the 2^500 guard, in ``phy.lp_solves``."""
         bits = np.asarray(bits, np.float64)
         B_tau = chan.cfg.pre_log
         lo, hi = 0.0, eta_upper_bound(chan, bits)

@@ -20,7 +20,8 @@ Sections (each present only when the trace carries its events):
   effective participation, straggler gap, buffer occupancy and
   dropped-upload totals;
 * counters — the total of each ``obs.counter`` over the session (as
-  ``phy.lp_solves``, the LPs the bisection power control solved);
+  ``phy.lp_solves``, the feasibility tests of the bisection power
+  control, one linear solve each);
 * recompilation summary — per-step trace counts from the retrace
   probes, flagging silent retrace storms;
 * profiler captures — directories of ``jax.profiler`` traces armed via
